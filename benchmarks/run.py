@@ -20,6 +20,7 @@ Run: PYTHONPATH=src python -m benchmarks.run
 """
 from __future__ import annotations
 
+import sys
 import time
 
 from . import (bench_async_serving, bench_continuous_batching,
@@ -48,14 +49,18 @@ SECTIONS = [
 
 
 def main() -> None:
+    failed = []
     for title, mod in SECTIONS:
         print(f"\n{'=' * 72}\n== {title}\n{'=' * 72}")
         t0 = time.time()
         try:
             mod.main()
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 - report, run the rest, fail
             print(f"SECTION FAILED: {type(e).__name__}: {e}")
+            failed.append(title)
         print(f"-- section took {time.time() - t0:.1f}s")
+    if failed:
+        sys.exit(f"{len(failed)} section(s) failed: {'; '.join(failed)}")
 
 
 if __name__ == "__main__":

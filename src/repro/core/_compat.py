@@ -1,10 +1,10 @@
-"""Version portability for the moving parts of the JAX API.
+"""The one mesh constructor every in-repo caller goes through.
 
-`shard_map` graduated from `jax.experimental.shard_map` to `jax.shard_map`,
-and its replication-checker kwarg was renamed `check_rep` -> `check_vma`
-along the way; `jax.make_mesh` is newer than the oldest JAX this repo
-supports. Every in-repo caller goes through these wrappers so the repo
-runs on both sides of each migration.
+`jax.make_mesh` gives `Explicit` axes by default, and the sharding
+annotations in this repo (`with_sharding_constraint` in
+`models.sharding`, `shard_map` over the macro mesh) are written for
+`Auto` axes, so every mesh is built here with `AxisType.Auto` on each
+axis.
 """
 from __future__ import annotations
 
@@ -12,55 +12,26 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape: Sequence[int], axis_names: Sequence[str],
-              devices: Optional[Sequence] = None):
-    """`jax.make_mesh(shape, axis_names)` across JAX versions.
+              devices: Optional[Sequence] = None) -> Mesh:
+    """A mesh of `shape` over `axis_names`, every axis `Auto`.
 
     `devices` restricts the mesh to an explicit device subset (in that
-    order) — `jax.make_mesh` has no such parameter, so subsetting always
-    takes the manual-Mesh construction. This is THE blessed multi-device
-    mesh entry point for retrieval (`core.sharded_index`) and serving
-    (`launch.mesh`): one place that knows how to build a Mesh everywhere.
+    order); None lets `jax.make_mesh` pick the device order. This is THE
+    multi-device mesh entry point for retrieval (`core.sharded_index`)
+    and serving (`launch.mesh`).
     """
-    from jax.sharding import Mesh
-
     shape = tuple(int(s) for s in shape)
+    names = tuple(axis_names)
+    auto = (AxisType.Auto,) * len(names)
     if devices is None:
-        try:
-            return jax.make_mesh(shape, tuple(axis_names))
-        except AttributeError:  # older jax: build the Mesh by hand
-            devices = jax.devices()
+        return jax.make_mesh(shape, names, axis_types=auto)
     n = int(np.prod(shape))
     if len(devices) < n:
         raise ValueError(
             f"mesh shape {shape} needs {n} devices, have {len(devices)}")
-    return Mesh(np.asarray(devices[:n]).reshape(shape), tuple(axis_names))
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_replication: bool = False):
-    """`jax.shard_map` across JAX versions.
-
-    check_replication=False disables the static replication checker (the
-    usual setting here: outputs ARE replicated via all_gather, but the
-    checker cannot prove it through top_k)."""
-    try:
-        sm = jax.shard_map
-    except AttributeError:  # older jax keeps it in experimental
-        from jax.experimental.shard_map import shard_map as sm
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return sm(f, **kwargs, check_vma=check_replication)
-    except TypeError:
-        return sm(f, **kwargs, check_rep=check_replication)
-
-
-def axis_size(name):
-    """`jax.lax.axis_size` across JAX versions (inside shard_map/pmap).
-
-    Older jax has no axis_size; psum of 1 over the axis is the identity."""
-    try:
-        return jax.lax.axis_size(name)
-    except AttributeError:
-        return jax.lax.psum(1, name)
+    return Mesh(np.asarray(devices[:n]).reshape(shape), names,
+                axis_types=auto)

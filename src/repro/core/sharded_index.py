@@ -34,7 +34,9 @@ Device parallelism: `parallelism="shard_map"` scores the stacked macro
 images over a REAL `jax.sharding.Mesh` — pass one explicitly via
 `build(..., mesh=launch.mesh.make_macro_mesh())` or let it default to a
 1-D mesh over every device — with per-device local scoring and a tiny
-all-gather, exact monolithic parity included. This module is also the
+all-gather, exact monolithic parity included. A mesh whose size does not
+divide `n_shards` is refused at build time: shard_map never quietly
+becomes a one-device vmap. This module is also the
 one blessed home of the pod-scale FLAT-index searcher
 (`make_distributed_searcher` / `shard_index_arrays`, folded from the
 retired `core.distributed`, which lives on as a deprecation shim).
@@ -42,7 +44,6 @@ retired `core.distributed`, which lives on as a deprecation shim).
 from __future__ import annotations
 
 import dataclasses
-import math
 from functools import partial
 from typing import Optional, Sequence
 
@@ -84,46 +85,37 @@ def _scores_impl(queries, values, scales, planes, norms, alive,
     args = (values, scales, planes, norms)
     if parallelism == "map":
         s = jax.lax.map(lambda t: shard_fn(*t), args)
-    elif parallelism == "shard_map" and cfg.path not in (
-        "kernel_bitserial", "kernel_mxu",
-    ):
-        s = _shard_map_scores(shard_fn, args, mesh=mesh)
-    else:  # "vmap", and shard_map's fallback for the Pallas paths
+    elif parallelism == "shard_map":
+        s = _shard_map_scores(shard_fn, args, mesh)
+    else:
         s = jax.vmap(shard_fn)(*args)
     return jnp.where(alive[:, None, :], s, _NEG_INF)
 
 
-def _shard_map_scores(shard_fn, args, mesh=None) -> jax.Array:
+def _shard_map_scores(shard_fn, args, mesh) -> jax.Array:
     """Distribute macros over a real device mesh along its leading axis.
 
     Each device scores its local block of shards (vmap inside the body)
     and the (S, b, cap) result is all-gathered back — candidate-list
     merging stays tiny exactly as in `make_distributed_searcher` below.
-    `mesh=None` builds a 1-D ("macro",) mesh over every available device
-    (`launch.mesh.make_macro_mesh` builds the same one explicitly);
-    falls back to plain vmap when the device count does not divide
-    n_shards, so a single-device host still runs the shard_map path's
-    semantics without error.
+    `ShardedDircIndex.build` has checked that the mesh size divides
+    n_shards.
     """
     from jax.sharding import PartitionSpec as P
 
-    from ._compat import make_mesh, shard_map
-
-    if mesh is None:
-        mesh = make_mesh((len(jax.devices()),), ("macro",))
     axes = mesh.axis_names
-    if args[0].shape[0] % math.prod(mesh.devices.shape):
-        return jax.vmap(shard_fn)(*args)
 
     def body(values, scales, planes_s, norms):
         local = jax.vmap(shard_fn)(values, scales, planes_s, norms)
         return jax.lax.all_gather(local, axes, axis=0, tiled=True)
 
-    mapped = shard_map(
+    # check_vma=False: the output IS replicated (all_gather over every
+    # mesh axis), but the checker cannot prove it through the kernels
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axes), P(axes), P(axes), P(axes)),
         out_specs=P(),
-        check_replication=False,
+        check_vma=False,
     )
     return mapped(*args)
 
@@ -214,7 +206,8 @@ class ShardedDircIndex:
         """`mesh` pins `parallelism="shard_map"` scoring to an explicit
         `jax.sharding.Mesh` (e.g. `launch.mesh.make_macro_mesh()`) —
         shards are split over its leading axis, one device group per
-        macro block. None scores over a 1-D mesh of all devices.
+        macro block. None scores over a 1-D mesh of all devices. Raises
+        ValueError when the mesh size does not divide `n_shards`.
 
         `drift` / `clock` configure the per-macro `DevicePhysics` channel
         (only meaningful with `config.error.enabled`): each shard gets
@@ -228,6 +221,16 @@ class ShardedDircIndex:
                 "mesh= only applies to parallelism='shard_map'")
         if n_shards < 1:
             raise ValueError("n_shards must be >= 1")
+        if parallelism == "shard_map":
+            if mesh is None:
+                from ._compat import make_mesh
+
+                mesh = make_mesh((len(jax.devices()),), ("macro",))
+            if n_shards % mesh.devices.size:
+                raise ValueError(
+                    f"parallelism='shard_map' needs n_shards ({n_shards}) "
+                    f"to be a multiple of the mesh's {mesh.devices.size} "
+                    "devices")
         emb = np.asarray(embeddings, np.float32)
         n, dim = emb.shape
         chunks = np.array_split(np.arange(n), n_shards)  # contiguous shards
@@ -638,11 +641,9 @@ class ShardedDircIndex:
 
 def _flat_axis_index(axis_names: Sequence[str]) -> jax.Array:
     """Linear device index over (possibly multiple) mesh axes."""
-    from ._compat import axis_size
-
     idx = jnp.int32(0)
     for name in axis_names:
-        idx = idx * axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx
 
 
@@ -690,19 +691,17 @@ def make_distributed_searcher(
     """
     from jax.sharding import PartitionSpec as P
 
-    from ._compat import shard_map
-
     doc_axes = tuple(doc_axes if doc_axes is not None else mesh.axis_names)
     doc_spec = P(doc_axes)
     body = partial(_local_search, k=k, metric=metric, axis_names=doc_axes)
-    shmapped = shard_map(
+    shmapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), doc_spec, doc_spec),
         out_specs=(P(), P()),
-        check_replication=False,  # outputs ARE replicated (all_gather over
-                                  # all doc axes + identical top_k); the
-                                  # checker cannot prove it through top_k
+        check_vma=False,  # outputs ARE replicated (all_gather over all
+                          # doc axes + identical top_k); the checker
+                          # cannot prove it through top_k
     )
 
     @jax.jit

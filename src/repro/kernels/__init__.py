@@ -7,7 +7,7 @@
                 the block table, new-token scatter folded into the launch
 
 ops.py = jit'd public wrappers; ref.py = pure-jnp oracles. All kernels are
-validated in interpret mode on CPU; the `REPRO_PALLAS_INTERPRET` env var
-(see _env.py) is the single interpret/compile switch — set it to 0 on TPU.
+validated in interpret mode on CPU and compile through Mosaic on a TPU;
+_env.py picks between the two from the default backend.
 """
 from . import ops, paged_attend, ref  # noqa: F401

@@ -1,23 +1,22 @@
-"""Interpret-mode switch shared by every Pallas kernel module.
+"""Interpret-or-compile choice shared by every Pallas kernel module.
 
-`REPRO_PALLAS_INTERPRET` is the single source of truth: CPU containers run
-the kernel bodies in interpret mode (the default); on TPU set it to ``0``
-to compile through Mosaic. Kernel modules default their public ``interpret``
-argument to ``None`` and resolve it here, so a direct call to any kernel —
-not just the `ops.py` wrappers — honours the env var.
-
-This lives in its own module (rather than `ops.py`, which re-exports
-`INTERPRET`) because `ops` imports the kernel modules: kernels importing
-`ops.INTERPRET` back would be circular.
+A kernel runs in interpret mode exactly when the default backend is not a
+TPU: CPU test runs interpret, a chip run compiles through Mosaic. Kernel
+modules default their public ``interpret`` argument to ``None`` and resolve
+it here while tracing, so the choice follows the process's platform and
+never a flag read at import. An explicit ``interpret=`` still wins, which
+lets ahead-of-time compile tests steer a kernel onto Mosaic from a CPU
+process.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+import jax
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """Default an ``interpret=None`` kernel argument to the env switch."""
-    return INTERPRET if interpret is None else interpret
+    """``interpret`` if given, else True unless the default backend is TPU."""
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() != "tpu"

@@ -1,8 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Handles padding to block multiples, layout transposition into the kernel
-layouts, and the interpret-mode switch (CPU containers run the kernel
-bodies in interpret mode; on TPU set REPRO_PALLAS_INTERPRET=0).
+Handles padding to block multiples and layout transposition into the
+kernel layouts. Each kernel picks interpret mode from the platform while
+it is traced (see `_env.resolve_interpret`).
 """
 from __future__ import annotations
 
@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import bitplane
-from ._env import INTERPRET
 from . import dirc_mac as _dirc
 from . import score_matmul as _score
 from . import topk_select as _topk
@@ -44,8 +43,7 @@ def dirc_mac(q_values: jax.Array, d_planes_packed: jax.Array, bits: int = 8,
     qp = bitplane.pack_words(bitplane.to_bitplanes(q_values, bits=bits))
     d = _pad_axis(d_planes_packed, 0, block_n)
     d_t = jnp.transpose(d, (1, 2, 0))  # (bits, nw, n_pad)
-    out = _dirc.dirc_mac_packed(qp, d_t, bits=bits, interpret=INTERPRET,
-                                block_n=block_n)[:, :n]
+    out = _dirc.dirc_mac_packed(qp, d_t, bits=bits, block_n=block_n)[:, :n]
     return out[0] if squeeze else out
 
 
@@ -58,7 +56,7 @@ def score_matmul(q: jax.Array, docs: jax.Array,
         q = q[None]
     n = docs.shape[0]
     d = _pad_axis(docs, 0, block_n)
-    out = _score.score_matmul_int(q, d, interpret=INTERPRET, block_n=block_n)[:, :n]
+    out = _score.score_matmul_int(q, d, block_n=block_n)[:, :n]
     return out[0] if squeeze else out
 
 
@@ -75,7 +73,7 @@ def score_matmul_cosine(q: jax.Array, docs: jax.Array, doc_norms: jax.Array,
     dn = _pad_axis(doc_norms, 0, block_n, value=1.0)[None, :]
     qn = jnp.sqrt(jnp.sum(q.astype(jnp.float32) ** 2, -1, keepdims=True))
     out = _score.score_matmul_cosine(
-        q, d, qn, dn.astype(jnp.float32), interpret=INTERPRET, block_n=block_n
+        q, d, qn, dn.astype(jnp.float32), block_n=block_n
     )[:, :n]
     return out[0] if squeeze else out
 
@@ -90,10 +88,10 @@ def local_topk_blocks(scores: jax.Array, k: int,
     b, n = scores.shape
     s = _pad_axis(scores, 1, block_n, value=_topk.NEG_INF)
     nb = s.shape[1] // block_n
-    vals, idx = _topk.blockwise_topk(s, k=k, interpret=INTERPRET, block_n=block_n)
-    offs = (jnp.arange(nb, dtype=jnp.int32) * block_n)[None, :, None]
-    gidx = (idx + offs).reshape(b, nb * k)
-    gvals = vals.reshape(b, nb * k)
+    vals, idx = _topk.blockwise_topk(s, k=k, block_n=block_n)  # (nb, b, k)
+    offs = (jnp.arange(nb, dtype=jnp.int32) * block_n)[:, None, None]
+    gidx = jnp.transpose(idx + offs, (1, 0, 2)).reshape(b, nb * k)
+    gvals = jnp.transpose(vals, (1, 0, 2)).reshape(b, nb * k)
     # Candidates are block-major, score-desc within block, low-index
     # tie-broken — top_k over them preserves the global low-index tie-break.
     fv, fpos = jax.lax.top_k(gvals, k)

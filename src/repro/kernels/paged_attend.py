@@ -11,13 +11,13 @@ Split-KV dataflow (flash-decoding):
     grid = (row, kv_chunk)   # one program per (b, chunk of the block table)
 
 Each program
-  1. scatters the new-token K/V that land inside its chunk into the
-     shared pools (the `_paged_write` fold-in — pools are aliased
-     input/outputs, so the write is in place and rows' chunks are
-     disjoint by construction; invalid lanes simply skip the write
-     instead of scribbling the NULL scratch block),
-  2. gathers only its `chunk_blocks` physical blocks through the block
-     table,
+  1. copies its `chunk_blocks` physical blocks from the HBM pools into a
+     VMEM buffer, one DMA per block, addressed through the block table;
+  2. writes the new-token K/V that land inside its chunk both into that
+     buffer and, by DMA, into the shared pools (the `_paged_write` fold-in
+     — pools are aliased input/outputs, so the write is in place and
+     rows' chunks are disjoint by construction; invalid lanes simply skip
+     the write instead of scribbling the NULL scratch block);
   3. computes scores for all `t` query positions against its chunk with
      a causal + true-length mask, keeping *local* softmax statistics:
      chunk max `m`, unnormalized weight sum `denom`, and weighted-value
@@ -30,12 +30,13 @@ The per-chunk `(acc, m, denom)` partials are reduced in a second pass
 online-softmax rescale, so long contexts parallelize over the KV axis
 instead of serializing per row.
 
-Layout notes: the block table and per-row length/n_valid scalars ride in
-SMEM; the K/V pools are unblocked `ANY`-space refs indexed dynamically
-per physical block (interpret mode executes this directly; a Mosaic
-build would double-buffer the per-block loads with `make_async_copy`).
-Like every kernel in this package it is validated in interpret mode on
-CPU; `REPRO_PALLAS_INTERPRET=0` compiles it for TPU.
+Layout notes: the block table and per-row length/n_valid scalars are
+scalar-prefetched into SMEM; the K/V pools stay unblocked in HBM
+(`pl.ANY`) and move only by DMA. Scores are one 2-D matmul of every
+query head against every (token, KV head) row of the chunk, with the
+rows of other KV heads masked out: that keeps each dot two-dimensional,
+which is what Mosaic lowers, at `n_kv_heads` times the score FLOPs of a
+per-head loop — small next to the bytes a decode step moves.
 """
 from __future__ import annotations
 
@@ -53,73 +54,87 @@ NEG_INF = -1.0e30
 # Target tokens per chunk: one program's KV tile. 128 keeps the score
 # matmul lane-aligned while bounding per-program VMEM.
 CHUNK_TOKENS = 128
+# Scoped VMEM the kernel may use: a prefill chunk's fp32 score tile
+# (t*h, chunk_tokens*kh) and its temporaries outgrow the default limit.
+VMEM_LIMIT_BYTES = 64 * 2**20
 
 
-def _paged_attend_kernel(table_ref, len_ref, nv_ref, q_ref, kn_ref, vn_ref,
-                         kpool_ref, vpool_ref,
+def _paged_attend_kernel(table_ref, len_ref, nv_ref,
+                         q_ref, kn_ref, vn_ref, kpool_ref, vpool_ref,
                          acc_ref, m_ref, den_ref, kout_ref, vout_ref,
-                         *, block_size: int, chunk_blocks: int, scale: float):
-    j = pl.program_id(1)
-    t, h, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    kh = kn_ref.shape[2]
+                         k_buf, v_buf, sem,
+                         *, block_size: int, chunk_blocks: int,
+                         table_width: int, n_heads: int, scale: float):
+    i, j = pl.program_id(0), pl.program_id(1)
+    t, kh, hd = kn_ref.shape[1], kn_ref.shape[2], kn_ref.shape[3]
+    h = n_heads
     g = h // kh
     bs, cb = block_size, chunk_blocks
     ct = cb * bs
+    row0 = i * table_width
+    length = len_ref[i]
+    n_valid = nv_ref[i]
 
-    length = len_ref[0]
-    n_valid = nv_ref[0]
+    # -- gather this chunk's physical blocks through the block table.
+    copies = []
+    for c in range(cb):
+        phys = table_ref[row0 + j * cb + c]
+        copies.append(pltpu.make_async_copy(kpool_ref.at[phys], k_buf.at[c],
+                                            sem))
+        copies.append(pltpu.make_async_copy(vpool_ref.at[phys], v_buf.at[c],
+                                            sem))
+    for cp in copies:
+        cp.start()
+    for cp in copies:
+        cp.wait()
 
-    # -- fused `_paged_write`: scatter the new tokens owned by this chunk.
-    # Each logical position belongs to exactly one (row, chunk) program,
-    # and live rows' physical blocks are disjoint (CoW barriers guarantee
-    # shared blocks are never write targets), so the in-place pool writes
-    # below never race.
-    for i in range(t):
-        pos = length + i
+    # -- fused `_paged_write`: each new token owned by this chunk goes into
+    # the VMEM copy (so this program attends it) and into the pool. Each
+    # logical position belongs to exactly one (row, chunk) program, and
+    # live rows' physical blocks are disjoint (CoW barriers guarantee
+    # shared blocks are never write targets), so the writes never race.
+    def write_token(ti, carry):
+        pos = length + ti
         lb = pos // bs
-        own = (lb >= j * cb) & (lb < (j + 1) * cb) & (i < n_valid)
-        phys = table_ref[0, lb]
-        off = pos % bs
+        own = (lb >= j * cb) & (lb < (j + 1) * cb) & (ti < n_valid)
 
         @pl.when(own)
         def _():
-            kout_ref[phys, off] = kn_ref[0, i].astype(kout_ref.dtype)
-            vout_ref[phys, off] = vn_ref[0, i].astype(vout_ref.dtype)
+            phys = table_ref[row0 + lb]
+            off = pos % bs
+            c = lb - j * cb
+            k_buf[c, off] = kn_ref[0, ti]
+            v_buf[c, off] = vn_ref[0, ti]
+            for src, dst in ((kn_ref, kout_ref), (vn_ref, vout_ref)):
+                cp = pltpu.make_async_copy(src.at[0, ti], dst.at[phys, off],
+                                           sem)
+                cp.start()
+                cp.wait()
 
-    # -- gather this chunk's physical blocks through the block table.
-    ks, vs = [], []
-    for c in range(cb):
-        phys = table_ref[0, j * cb + c]
-        ks.append(kpool_ref[phys])
-        vs.append(vpool_ref[phys])
-    kc = jnp.concatenate(ks, axis=0)                      # (ct, kh, hd)
-    vc = jnp.concatenate(vs, axis=0)
+        return carry
 
-    # Overlay the new tokens in-register: the aliased pool read above may
-    # predate this program's own scatter, and the overlay keeps compute
-    # independent of cross-buffer read-after-write ordering.
-    local_iota = jax.lax.broadcasted_iota(jnp.int32, (ct, 1), 0)[:, 0]
-    for i in range(t):
-        hit = (local_iota == length + i - j * ct) & (i < n_valid)
-        kc = jnp.where(hit[:, None, None], kn_ref[0, i][None], kc)
-        vc = jnp.where(hit[:, None, None], vn_ref[0, i][None], vc)
+    jax.lax.fori_loop(0, t, write_token, 0)
 
-    # -- local online-softmax statistics for this chunk.
-    q = q_ref[0].astype(jnp.float32).reshape(t, kh, g, hd) * scale
-    s = jnp.einsum("tkgd,skd->tkgs", q, kc.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
-    kv_pos = j * ct + local_iota
-    q_pos = length + jax.lax.broadcasted_iota(jnp.int32, (t, 1), 0)[:, 0]
-    visible = kv_pos[None, :] <= q_pos[:, None]           # (t, ct)
-    s = jnp.where(visible[:, None, None, :], s, NEG_INF)
-    m = jnp.max(s, axis=-1)                               # (t, kh, g)
-    p = jnp.where(visible[:, None, None, :], jnp.exp(s - m[..., None]), 0.0)
-    den = jnp.sum(p, axis=-1)
-    acc = jnp.einsum("tkgs,skd->tkgd", p.astype(vc.dtype), vc,
-                     preferred_element_type=jnp.float32)
-    acc_ref[0, 0] = acc.reshape(t, h, hd)
-    m_ref[0, 0] = m.reshape(t, h)
-    den_ref[0, 0] = den.reshape(t, h)
+    # -- local online-softmax statistics for this chunk. Rows are
+    # (query position, head), columns (chunk token, KV head).
+    kc = k_buf[...].astype(jnp.float32).reshape(ct * kh, hd)
+    vc = v_buf[...].astype(jnp.float32).reshape(ct * kh, hd)
+    q = q_ref[0].astype(jnp.float32) * scale              # (t*h, hd)
+    s = jax.lax.dot_general(q, kc, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    visible = ((row % h) // g == col % kh) & (
+        j * ct + col // kh <= length + row // h)
+    s = jnp.where(visible, s, NEG_INF)
+    m = jnp.max(s, axis=1, keepdims=True)                 # (t*h, 1)
+    p = jnp.where(visible, jnp.exp(s - m), 0.0)
+    den = jnp.sum(p, axis=1, keepdims=True)
+    acc = jnp.dot(p.astype(v_buf.dtype), vc.astype(v_buf.dtype),
+                  preferred_element_type=jnp.float32)     # (t*h, hd)
+    acc_ref[0, 0] = acc
+    m_ref[0, 0] = m
+    den_ref[0, 0] = den
 
 
 @functools.partial(jax.jit, static_argnames=("chunk_blocks", "interpret"))
@@ -152,45 +167,56 @@ def paged_attend_fused(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
         block_table = jnp.pad(block_table, ((0, 0), (0, mb_p - mb)))
     nc = mb_p // cb
 
-    smem = pltpu.TPUMemorySpace.SMEM
-    anym = pltpu.TPUMemorySpace.ANY
-    acc, m, den, kp, vp = pl.pallas_call(
-        functools.partial(_paged_attend_kernel, block_size=bs,
-                          chunk_blocks=cb, scale=hd**-0.5),
+    row_block = lambda i, j, *_: (i, 0, 0)          # noqa: E731
+    new_block = lambda i, j, *_: (i, 0, 0, 0)       # noqa: E731
+    part_block = lambda i, j, *_: (i, j, 0, 0)      # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
         grid=(b, nc),
         in_specs=[
-            pl.BlockSpec((1, mb_p), lambda i, j: (i, 0), memory_space=smem),
-            pl.BlockSpec((1,), lambda i, j: (i,), memory_space=smem),
-            pl.BlockSpec((1,), lambda i, j: (i,), memory_space=smem),
-            pl.BlockSpec((1, t, h, hd), lambda i, j: (i, 0, 0, 0)),
-            pl.BlockSpec((1, t, kh, hd), lambda i, j: (i, 0, 0, 0)),
-            pl.BlockSpec((1, t, kh, hd), lambda i, j: (i, 0, 0, 0)),
-            pl.BlockSpec(memory_space=anym),
-            pl.BlockSpec(memory_space=anym),
+            pl.BlockSpec((1, t * h, hd), row_block),
+            pl.BlockSpec((1, t, kh, hd), new_block),
+            pl.BlockSpec((1, t, kh, hd), new_block),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, t, h, hd), lambda i, j: (i, j, 0, 0, 0)),
-            pl.BlockSpec((1, 1, t, h), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, t, h), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec(memory_space=anym),
-            pl.BlockSpec(memory_space=anym),
+            pl.BlockSpec((1, 1, t * h, hd), part_block),
+            pl.BlockSpec((1, 1, t * h, 1), part_block),
+            pl.BlockSpec((1, 1, t * h, 1), part_block),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
+        scratch_shapes=[
+            pltpu.VMEM((cb, bs, kh, hd), k_pool.dtype),
+            pltpu.VMEM((cb, bs, kh, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA(()),
+        ],
+    )
+    acc, m, den, kp, vp = pl.pallas_call(
+        functools.partial(_paged_attend_kernel, block_size=bs,
+                          chunk_blocks=cb, table_width=mb_p, n_heads=h,
+                          scale=hd**-0.5),
+        grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, nc, t, h, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, nc, t, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, nc, t, h), jnp.float32),
+            jax.ShapeDtypeStruct((b, nc, t * h, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, nc, t * h, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, nc, t * h, 1), jnp.float32),
             jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
             jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
         ],
         input_output_aliases={6: 3, 7: 4},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=resolve_interpret(interpret),
-    )(block_table, length.astype(jnp.int32), n_valid.astype(jnp.int32),
-      q, k_new, v_new, k_pool, v_pool)
+    )(block_table.reshape(-1).astype(jnp.int32), length.astype(jnp.int32),
+      n_valid.astype(jnp.int32), q.reshape(b, t * h, hd),
+      k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype), k_pool, v_pool)
 
     # -- second pass: flash-decoding combine of the per-chunk partials.
-    big = jnp.max(m, axis=1)                              # (b, t, h)
-    alpha = jnp.exp(m - big[:, None])                     # (b, nc, t, h)
+    big = jnp.max(m, axis=1)                              # (b, t*h, 1)
+    alpha = jnp.exp(m - big[:, None])                     # (b, nc, t*h, 1)
     den_tot = jnp.sum(den * alpha, axis=1)
-    out = jnp.sum(acc * alpha[..., None], axis=1)
-    out = out / jnp.maximum(den_tot, 1e-30)[..., None]
-    return out.astype(q.dtype), kp, vp
+    out = jnp.sum(acc * alpha, axis=1)
+    out = out / jnp.maximum(den_tot, 1e-30)
+    return out.reshape(b, t, h, hd).astype(q.dtype), kp, vp

@@ -38,9 +38,10 @@ def score_matmul_cosine(
 
 
 def blockwise_topk(scores: jax.Array, k: int, block_n: int):
-    """Oracle for kernels.topk_select: per-block top-k, low-index tie-break."""
+    """Oracle for kernels.topk_select: per-block top-k, low-index
+    tie-break, block axis first ((nb, b, k) like the kernel)."""
     b, n = scores.shape
     nb = n // block_n
-    s = scores.reshape(b, nb, block_n)
+    s = jnp.transpose(scores.reshape(b, nb, block_n), (1, 0, 2))
     vals, idx = jax.lax.top_k(s, k)
     return vals, idx.astype(jnp.int32)

@@ -25,11 +25,12 @@ BLOCK_N = 128
 
 
 def _score_kernel(q_ref, d_ref, out_ref):
-    # q: (b, dim) int8, d: (blk_n, dim) int8 -> out (b, blk_n) int32
-    q = q_ref[:, :].astype(jnp.int32)
-    d = d_ref[:, :].astype(jnp.int32)
+    # q: (b, dim) int8, d: (blk_n, dim) int8 -> out (b, blk_n) int32.
+    # The int8 operands go to the MXU as they are: Mosaic has no int32
+    # matmul, only int8 x int8 with int32 accumulation.
     out_ref[:, :] = jax.lax.dot_general(
-        q, d, (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32
+        q_ref[:, :], d_ref[:, :], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32,
     )
 
 

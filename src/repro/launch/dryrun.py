@@ -109,7 +109,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                 "output_gib": ma.output_size_in_bytes / 2**30,
                 "fits_16gib": (ma.argument_size_in_bytes
                                + ma.temp_size_in_bytes)
-                < roofline.HBM_BYTES,
+                < roofline.peaks_for(roofline.DRYRUN_DEVICE_KIND).hbm_bytes,
             },
             raw_cost=raw,
         )

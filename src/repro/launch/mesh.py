@@ -16,18 +16,20 @@ from __future__ import annotations
 
 import jax
 
+from repro.core._compat import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(model_par: int = 1):
     """Whatever this host has — for tests and CPU examples."""
     n = len(jax.devices())
     assert n % model_par == 0
-    return jax.make_mesh((n // model_par, model_par), ("data", "model"))
+    return make_mesh((n // model_par, model_par), ("data", "model"))
 
 
 def make_macro_mesh(n_devices: int | None = None):
@@ -36,8 +38,6 @@ def make_macro_mesh(n_devices: int | None = None):
     scores over — pass it as `build(..., mesh=...)` (or let the index
     default to all devices). `n_devices=None` uses every device.
     """
-    from repro.core._compat import make_mesh
-
     devs = jax.devices()
     n = len(devs) if n_devices is None else n_devices
     return make_mesh((n,), ("macro",), devices=devs)

@@ -33,6 +33,10 @@ decodes; the report gains an "slo" counter block. --json FILE
 ('-' = stdout) additionally emits any --rag report as machine-readable
 JSON.
 
+The model runs its SMOKE config unless --no-smoke asks for the
+architecture's published widths. Any failed request makes the run exit
+non-zero.
+
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch mamba2-2.7b --smoke \
       --batch 4 --prompt-len 16 --new-tokens 32
@@ -62,6 +66,7 @@ from repro.configs import get_config
 from repro.core.device_physics import DriftConfig
 from repro.core.error_model import ErrorModelConfig
 from repro.core.retrieval import RetrievalConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import (
     AsyncBatchScheduler,
@@ -82,7 +87,7 @@ def serve(arch: str, smoke: bool = True, batch: int = 4,
           temperature: float = 0.0, seed: int = 0) -> dict:
     cfg = get_config(arch, smoke=smoke)
     model = build_model(cfg)
-    params = model.init(jax.random.key(seed))
+    params = init_params(model, seed)
     engine = GenerationEngine(model, params, temperature=temperature)
     prompts = jax.random.randint(
         jax.random.key(seed + 1), (batch, prompt_len), 0, cfg.vocab_size)
@@ -182,6 +187,16 @@ def _jsonable(obj):
     return obj
 
 
+def _exit_on_failures(out: dict) -> None:
+    """Exit non-zero when any request failed or its retrieval never
+    reached generation: a report with failures is not a successful run."""
+    failed = out.get("n_failed", 0) + out.get("n_chain_failed", 0)
+    if failed:
+        sys.exit(f"{failed} request(s) failed "
+                 f"(n_failed {out.get('n_failed', 0)}, "
+                 f"n_chain_failed {out.get('n_chain_failed', 0)})")
+
+
 def _emit_json(out: dict, dest: str) -> None:
     """Write the open-loop report as JSON to `dest` ('-' = stdout)."""
     payload = json.dumps(_jsonable(out), indent=2, sort_keys=True)
@@ -224,9 +239,17 @@ def _percentiles_ms(wait_s) -> dict:
     }
 
 
+def init_params(model, seed: int):
+    """Seeded weights, initialized under jit so each leaf is produced in
+    its parameter dtype without an eager fp32 copy of the stacked layers
+    (a full-width model would otherwise peak far above its bf16 size)."""
+    return jax.jit(model.init)(jax.random.key(seed))
+
+
 def build_rag_pipeline(n_docs: int = 512, n_shards: int = 4, dim: int = 256,
                        path: str = "int_exact", seed: int = 0,
                        arch: Optional[str] = None,
+                       smoke: bool = True,
                        max_prompt_len: int = 96,
                        sense_errors: bool = False,
                        drift_mag: float = 0.0,
@@ -234,8 +257,10 @@ def build_rag_pipeline(n_docs: int = 512, n_shards: int = 4, dim: int = 256,
                        clock=time.monotonic) -> RagPipeline:
     """A ShardedDircIndex-backed pipeline over a synthetic corpus.
 
-    Passing `arch` attaches a smoke-size generator model, enabling the
-    generation paths (`query_stream(generate=True)`, `decode_engine`).
+    Passing `arch` attaches a generator model with seeded weights,
+    enabling the generation paths (`query_stream(generate=True)`,
+    `decode_engine`): its SMOKE config by default, its published FULL
+    widths with `smoke=False`.
 
     `sense_errors=True` turns on the per-macro device-physics channel
     (jittered per-shard calibration, error-aware remapping, Sigma-D
@@ -250,9 +275,8 @@ def build_rag_pipeline(n_docs: int = 512, n_shards: int = 4, dim: int = 256,
         f"w{rng.integers(0, 997)}" for _ in range(12)) for i in range(n_docs)]
     model = params = None
     if arch is not None:
-        cfg = get_config(arch, smoke=True)
-        model = build_model(cfg)
-        params = model.init(jax.random.key(seed))
+        model = build_model(get_config(arch, smoke=smoke))
+        params = init_params(model, seed)
     if sense_errors:
         retrieval = RetrievalConfig(
             bits=8, metric="cosine", path=path, mapping="error_aware",
@@ -423,7 +447,8 @@ def serve_rag_open_loop_generate(
         n_replicas: Optional[int] = None,
         affinity: Optional[bool] = None,
         max_imbalance: Optional[int] = None,
-        arch: str = "phi4-mini-3.8b", path: str = "int_exact",
+        arch: str = "phi4-mini-3.8b", smoke: bool = True,
+        path: str = "int_exact",
         seed: int = 0, sense_errors: bool = False, drift_mag: float = 0.0,
         recal: bool = False,
         slo: Optional[SLOConfig] = None,
@@ -473,7 +498,7 @@ def serve_rag_open_loop_generate(
     if pipe is None:
         pipe = build_rag_pipeline(n_docs=n_docs, n_shards=n_shards, dim=dim,
                                   path=path, seed=seed, arch=arch,
-                                  sense_errors=sense_errors,
+                                  smoke=smoke, sense_errors=sense_errors,
                                   drift_mag=drift_mag, recal=recal)
     if pipe.engine is None:
         raise ValueError("generate mode needs a pipeline with a model "
@@ -635,7 +660,10 @@ def serve_rag_open_loop_generate(
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="run the architecture's SMOKE config (default); "
+                         "--no-smoke runs its published FULL widths")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--new-tokens", type=int, default=32)
@@ -748,6 +776,7 @@ def main() -> None:
                          "('-' = stdout), alongside the human-readable "
                          "report")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.rag and args.open_loop and args.generate:
         config = EngineConfig(
             n_slots=args.n_slots, paged=args.paged,
@@ -773,7 +802,7 @@ def main() -> None:
             config=config,
             n_replicas=args.n_replicas, affinity=args.affinity,
             max_imbalance=args.max_imbalance,
-            arch=args.arch or "phi4-mini-3.8b",
+            arch=args.arch or "phi4-mini-3.8b", smoke=args.smoke,
             sense_errors=args.sense_errors, drift_mag=args.drift_mag,
             recal=args.recal,
             slo=slo, hi_pri_tenants=args.hi_pri_tenants)
@@ -835,6 +864,7 @@ def main() -> None:
         _print_retrieval_stats(out)
         if args.json:
             _emit_json(out, args.json)
+        _exit_on_failures(out)
         return
     if args.rag and args.open_loop:
         out = serve_rag_open_loop(
@@ -854,6 +884,7 @@ def main() -> None:
         _print_retrieval_stats(out)
         if args.json:
             _emit_json(out, args.json)
+        _exit_on_failures(out)
         return
     if args.rag:
         out = serve_rag(n_docs=args.rag_docs, n_shards=args.n_shards,
@@ -869,8 +900,9 @@ def main() -> None:
         return
     if not args.arch:
         ap.error("--arch is required unless --rag is set")
-    out = serve(args.arch, batch=args.batch, prompt_len=args.prompt_len,
-                new_tokens=args.new_tokens, temperature=args.temperature)
+    out = serve(args.arch, smoke=args.smoke, batch=args.batch,
+                prompt_len=args.prompt_len, new_tokens=args.new_tokens,
+                temperature=args.temperature)
     print(f"generated {out['tokens'].shape} tokens in {out['wall_s']:.2f}s "
           f"({out['tok_per_s']:.0f} tok/s)")
     print(out["tokens"][:2])

@@ -18,8 +18,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core._compat import axis_size
-
 
 def _q(x: jax.Array):
     absmax = jnp.max(jnp.abs(x))
@@ -47,7 +45,7 @@ def compressed_psum(grads, err, axis_names: Sequence[str]):
     """
     n = 1
     for a in axis_names:
-        n *= axis_size(a)
+        n *= jax.lax.axis_size(a)
 
     def one(g, e):
         g32 = g.astype(jnp.float32) + e

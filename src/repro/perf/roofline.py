@@ -1,16 +1,15 @@
 """Roofline-term derivation from compiled dry-run artifacts.
 
-Hardware model (TPU v5e-class, per chip):
-    peak compute   197 TFLOP/s bf16
-    HBM bandwidth  819 GB/s    (16 GiB capacity)
-    ICI link       ~50 GB/s per link
+Hardware model: per-chip peaks from `PEAKS`, keyed by the `device_kind`
+JAX reports. A device missing from the table is an error, never a
+default (see `peaks_for`).
 
 Terms (seconds, PER STEP, computed from per-device quantities — the
 "/ chips" in the spec formulas cancels because the SPMD program IS the
 per-device program):
-    compute_s    = HLO_FLOPs_per_device    / 197e12
-    memory_s     = HLO_bytes_per_device    / 819e9
-    collective_s = collective_bytes_per_device / 50e9
+    compute_s    = HLO_FLOPs_per_device    / peak bf16 FLOP/s
+    memory_s     = HLO_bytes_per_device    / peak HBM bytes/s
+    collective_s = collective_bytes_per_device / per-link ICI bytes/s
 
 ## The scan-trip-count correction (IMPORTANT)
 
@@ -40,10 +39,35 @@ from __future__ import annotations
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
-HBM_BYTES = 16 * 2**30
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float       # bf16 FLOP/s
+    hbm_bw: float      # HBM bytes/s
+    hbm_bytes: int     # HBM capacity
+    link_bw: float     # ICI bytes/s per link
+
+
+# Source: Google Cloud TPU documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s inter-chip
+# interconnect over four links (~50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9,
+                             hbm_bytes=16 * 2**30, link_bw=50e9),
+}
+# The chip the dry-run cells are compiled for (`launch.dryrun`).
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """Per-chip peaks for a JAX `device_kind`; raises for any other."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table entry for device_kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -109,16 +133,20 @@ class CellAnalysis:
     memory_output_bytes: int = 0
 
     @property
+    def peaks(self) -> ChipPeaks:
+        return peaks_for(DRYRUN_DEVICE_KIND)
+
+    @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks.flops
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / LINK_BW
+        return self.collective_bytes / self.peaks.link_bw
 
     @property
     def bottleneck(self) -> str:

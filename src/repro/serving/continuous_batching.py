@@ -107,6 +107,15 @@ from .paged_cache import PagedCacheManager, blocks_for, pow2_at_least
 _DONE = object()  # token_stream sentinel
 
 
+def _single_device(tree):
+    """The one device every array leaf of `tree` lives on, else None."""
+    devices = set()
+    for leaf in jax.tree_util.tree_leaves(tree):
+        if isinstance(leaf, jax.Array):
+            devices |= leaf.devices()
+    return devices.pop() if len(devices) == 1 else None
+
+
 class GenerationTicket:
     """Future-style handle for one generation request.
 
@@ -375,6 +384,9 @@ class ContinuousBatchingEngine:
         host_blocks = config.host_blocks or 0
         self.model = model
         self.params = params
+        # the device the weights live on: caches and pools are made there
+        # too, so a replica placed on its own chip keeps all its state there
+        self.device = _single_device(params)
         self.replica_id = replica_id
         self.n_slots = n_slots
         self.cache_len = cache_len
@@ -436,7 +448,8 @@ class ContinuousBatchingEngine:
                 on_swapin=self._swapin_prefix if self.host_blocks else None,
                 on_host_drop=(
                     self._drop_host_prefix if self.host_blocks else None))
-            self._pools = model.init_paged_caches(n_blocks, block_size)
+            with jax.default_device(self.device):
+                self._pools = model.init_paged_caches(n_blocks, block_size)
             self.paged_kernel = paged_kernel
             if paged_kernel is None:
                 # model decides (cfg.paged_kernel); also keeps duck-typed
@@ -457,7 +470,8 @@ class ContinuousBatchingEngine:
         else:
             self._batch_axes = self._detect_batch_axes()
             self._write_slot = jax.jit(self._write_slot_impl)
-            self._caches = model.init_caches(n_slots, cache_len, 0)
+            with jax.default_device(self.device):
+                self._caches = model.init_caches(n_slots, cache_len, 0)
 
         self._pad_id = eos_id if eos_id is not None else 0
         self._cur = np.full((n_slots, 1), self._pad_id, np.int32)

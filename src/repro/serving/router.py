@@ -15,7 +15,9 @@ measured hit rate collapses toward `(k - N) / k`.
 
 * **Replication.** N engines, each built from the SAME `EngineConfig`
   (replica shape) under one `RouterConfig` (fleet shape) — see
-  serving/config.py. Weights/params are shared read-only; every replica
+  serving/config.py. Replica i holds its weights on local device
+  `i % len(jax.local_devices())` (one chip per replica on a multi-chip
+  host; one shared copy where the host has one device); every replica
   owns its pool, caches, and (in threaded mode) decode loop.
 * **Prefix-affinity placement.** `submit()` derives the request's
   prefix content key with the engine's own derivation
@@ -73,8 +75,9 @@ from .continuous_batching import ContinuousBatchingEngine, GenerationTicket
 class EngineRouter:
     """Prefix-affinity load balancer over N replicated decode engines.
 
-    model/params: shared read-only by every replica (any Model-protocol
-        object the engine accepts).
+    model/params: the model (any Model-protocol object the engine
+        accepts) and its weights, which each replica holds on its own
+        local device (round-robin over `jax.local_devices()`).
     config: the per-replica `EngineConfig` — every replica is built from
         this ONE config (per-knob engine arguments are not accepted
         here; the fleet exists to replicate a fixed shape).
@@ -123,9 +126,11 @@ class EngineRouter:
                               else self.router.max_imbalance)
         keys = (jax.random.split(key, self.n_replicas)
                 if key is not None else [None] * self.n_replicas)
+        devices = jax.local_devices()
         self.engines: list[ContinuousBatchingEngine] = [
             ContinuousBatchingEngine(
-                model, params, config=config, replica_id=i,
+                model, jax.device_put(params, devices[i % len(devices)]),
+                config=config, replica_id=i,
                 eos_id=eos_id, temperature=temperature, key=keys[i],
                 clock=clock, start=start)
             for i in range(self.n_replicas)

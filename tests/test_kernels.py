@@ -81,16 +81,55 @@ def test_property_kernel_exactness(seed, bits):
     assert (got == want).all()
 
 
-# ----------------------------------------------- interpret-default plumbing
-def test_public_kernels_default_interpret_to_env_switch():
-    """Regression: public jitted kernel entry points hard-coded
-    interpret=True, silently pinning compiled deployments to interpret
-    mode unless every caller overrode it. They must default to None and
-    resolve through the REPRO_PALLAS_INTERPRET env switch."""
+# ------------------------------------------ interpret mode from the platform
+def _platform_is(monkeypatch, name, calls=None):
+    """Make the kernels see `name` as the default backend (counting
+    queries in `calls`)."""
+    from repro.kernels import _env
+
+    def fake():
+        if calls is not None:
+            calls.append(name)
+        return name
+
+    monkeypatch.setattr(_env.jax, "default_backend", fake)
+
+
+def _case_cpu_interprets(monkeypatch):
+    from repro.kernels._env import resolve_interpret
+
+    _platform_is(monkeypatch, "cpu")
+    assert resolve_interpret(None) is True
+
+
+def _case_tpu_compiles(monkeypatch):
+    from repro.kernels._env import resolve_interpret
+
+    _platform_is(monkeypatch, "tpu")
+    assert resolve_interpret(None) is False
+
+
+def _case_explicit_false_honoured(monkeypatch):
+    from repro.kernels._env import resolve_interpret
+
+    _platform_is(monkeypatch, "cpu")
+    assert resolve_interpret(False) is False
+
+
+def _case_explicit_true_honoured(monkeypatch):
+    from repro.kernels._env import resolve_interpret
+
+    _platform_is(monkeypatch, "tpu")
+    assert resolve_interpret(True) is True
+
+
+def _case_public_kernels_default_to_none(monkeypatch):
+    """Public kernel entry points must leave `interpret` unset so the
+    platform decides; hard-coding it would pin a chip run to the
+    interpreter (or a CPU run to Mosaic)."""
     import inspect
 
-    from repro.kernels import (_env, dirc_mac, paged_attend, score_matmul,
-                               topk_select)
+    from repro.kernels import dirc_mac, paged_attend, score_matmul, topk_select
 
     fns = [score_matmul.score_matmul_int, score_matmul.score_matmul_cosine,
            dirc_mac.dirc_mac_packed, topk_select.blockwise_topk,
@@ -98,28 +137,30 @@ def test_public_kernels_default_interpret_to_env_switch():
     for fn in fns:
         default = inspect.signature(fn).parameters["interpret"].default
         assert default is None, f"{fn.__name__} hard-codes interpret"
-    assert _env.resolve_interpret(None) is _env.INTERPRET
-    assert _env.resolve_interpret(True) is True
-    assert _env.resolve_interpret(False) is False
 
 
-@pytest.mark.parametrize("val,expect", [("0", False), ("1", True)])
-def test_interpret_env_switch_subprocess(val, expect):
-    """REPRO_PALLAS_INTERPRET is the single source of truth, read once at
-    import: exercised in a fresh interpreter per value."""
-    import os
-    import subprocess
-    import sys
+def _case_ops_resolve_at_call_time(monkeypatch):
+    """The ops wrappers ask for the platform while they are traced, not
+    when the module is imported: a fresh shape consults it again."""
+    calls = []
+    _platform_is(monkeypatch, "cpu", calls)
+    assert not hasattr(ops, "INTERPRET")
+    q = jnp.ones((1, 160), jnp.int8)
+    d = jnp.ones((131, 160), jnp.int8)
+    assert np.asarray(ops.score_matmul(q, d)).shape == (1, 131)
+    assert calls, "score_matmul traced without consulting the platform"
 
-    code = (
-        "from repro.kernels import _env, ops\n"
-        f"assert _env.INTERPRET is {expect}, _env.INTERPRET\n"
-        f"assert ops.INTERPRET is {expect}\n"
-        f"assert _env.resolve_interpret(None) is {expect}\n"
-    )
-    env = os.environ.copy()
-    env["REPRO_PALLAS_INTERPRET"] = val
-    env["PYTHONPATH"] = "src"
-    proc = subprocess.run([sys.executable, "-c", code], env=env, cwd=".",
-                          capture_output=True, text=True, timeout=120)
-    assert proc.returncode == 0, proc.stderr
+
+INTERPRET_CASES = {
+    "cpu_interprets": _case_cpu_interprets,
+    "tpu_compiles": _case_tpu_compiles,
+    "explicit_false_honoured": _case_explicit_false_honoured,
+    "explicit_true_honoured": _case_explicit_true_honoured,
+    "public_kernels_default_to_none": _case_public_kernels_default_to_none,
+    "ops_resolve_at_call_time": _case_ops_resolve_at_call_time,
+}
+
+
+@pytest.mark.parametrize("case", sorted(INTERPRET_CASES))
+def test_interpret_resolves_from_platform(monkeypatch, case):
+    INTERPRET_CASES[case](monkeypatch)

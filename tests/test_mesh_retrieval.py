@@ -124,8 +124,46 @@ _SUBPROC = textwrap.dedent("""
     fv, fi = jax.lax.top_k(ip / jnp.maximum(qn * norms[None, :], 1e-12), 8)
     ok_flat = bool((res.indices == fi).all())
 
+    # 4) a mesh whose size does not divide n_shards is refused, never
+    #    quietly scored as a one-device vmap
+    try:
+        ShardedDircIndex.build(emb, cfg, n_shards=6,
+                               parallelism="shard_map", mesh=mesh)
+        ok_refuse = False
+    except ValueError:
+        ok_refuse = True
+
+    # 5) replica fleet: one replica (weights and pool) per device, greedy
+    #    tokens identical to one engine's
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serving import (ContinuousBatchingEngine, EngineConfig,
+                               EngineRouter, RouterConfig)
+    mcfg = get_config("phi4-mini-3.8b", smoke=True)
+    model = build_model(mcfg)
+    params = model.init(jax.random.key(0))
+    ecfg = EngineConfig(n_slots=2, paged=True, cache_len=64, block_size=8,
+                        prefill_chunk=8)
+    prompts = [rng.integers(0, mcfg.vocab_size, size=n)
+               for n in (5, 12, 9, 20)]
+    single = ContinuousBatchingEngine(model, params, ecfg)
+    want_t = [single.submit(p, max_new_tokens=4) for p in prompts]
+    single.run_until_drained()
+    fleet = EngineRouter(model, params, ecfg, RouterConfig(n_replicas=4))
+    got_t = [fleet.submit(p, max_new_tokens=4) for p in prompts]
+    fleet.run_until_drained()
+    placed = [e.device for e in fleet.engines]
+    ok_devices = len(set(placed)) == 4 and all(
+        leaf.devices() == {e.device}
+        for e in fleet.engines
+        for leaf in jax.tree_util.tree_leaves((e.params, e._pools)))
+    ok_router = all(list(a.result()) == list(b.result())
+                    for a, b in zip(want_t, got_t))
+
     print(json.dumps({"ok_topk": ok_topk, "ok_scores": ok_scores,
-                      "ok_default": ok_default, "ok_flat": ok_flat}))
+                      "ok_default": ok_default, "ok_flat": ok_flat,
+                      "ok_refuse": ok_refuse, "ok_devices": ok_devices,
+                      "ok_router": ok_router}))
 """) % os.path.join(REPO, "src")
 
 
@@ -138,3 +176,6 @@ def test_shard_map_multidevice_parity_subprocess():
     assert out["ok_scores"], "mesh scores != monolithic scores"
     assert out["ok_default"], "default mesh != monolithic top-k"
     assert out["ok_flat"], "folded flat searcher != flat top-k"
+    assert out["ok_refuse"], "shard_map accepted a mesh not dividing shards"
+    assert out["ok_devices"], "replicas do not each hold their own device"
+    assert out["ok_router"], "routed greedy tokens != one engine's"

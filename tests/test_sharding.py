@@ -57,9 +57,10 @@ _SUBPROC = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     # 1) distributed retrieval: local-topk + gather == flat topk
-    from repro.core import distributed as D
+    from repro.core import sharded_index as D
     from repro.core import quantization as Q
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.core._compat import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     emb = rng.normal(size=(512, 128)).astype(np.float32)
     docs = Q.quantize(jnp.asarray(emb), bits=8)
@@ -93,7 +94,7 @@ _SUBPROC = textwrap.dedent("""
         p2, o2, m2 = jax.jit(art.fn)(params, opt, batch)
     loss_sharded = float(m2["loss"])
     # single-device reference
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh1 = make_mesh((1, 1), ("data", "model"))
     art1 = build_train_step(cfg, mesh1, adamw.AdamWConfig(), grad_accum=1)
     with mesh1:
         p1, o1, m1 = jax.jit(art1.fn)(params, opt, batch)
@@ -102,15 +103,14 @@ _SUBPROC = textwrap.dedent("""
 
     # 3) grad compression inside shard_map
     from repro.optim.grad_compression import compressed_psum
-    from repro.core._compat import shard_map
-    gmesh = jax.make_mesh((8,), ("data",))
+    gmesh = make_mesh((8,), ("data",))
     g = {"w": jnp.arange(8.0).reshape(8, 1) * jnp.ones((8, 4))}
     e = {"w": jnp.zeros((8, 4))}
     def body(gl, el):
         return compressed_psum(gl, el, ("data",))
-    out, new_e = shard_map(
+    out, new_e = jax.shard_map(
         body, mesh=gmesh, in_specs=(P("data"), P("data")),
-        out_specs=(P("data"), P("data")), check_replication=True)(g, e)
+        out_specs=(P("data"), P("data")), check_vma=True)(g, e)
     # mean over 8 shards of rows 0..7 -> 3.5 everywhere (within int8 quant)
     ok3 = bool(np.allclose(np.asarray(out["w"]), 3.5, atol=0.05))
 
