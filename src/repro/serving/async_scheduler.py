@@ -41,6 +41,7 @@ from collections import deque
 from typing import Callable, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class SchedulerError(RuntimeError):
@@ -351,37 +352,41 @@ class AsyncBatchScheduler:
         return chunk
 
     def _run_chunk(self, chunk: list, raise_errors: bool) -> int:
-        """Search one formed chunk and finish its tickets (no lock held)."""
-        k = max(t.k for t in chunk)
-        try:
-            ids, scores = self._search([t.text for t in chunk], k)
-        except Exception as e:  # noqa: BLE001 - converted to per-ticket errors
-            err = SchedulerError(f"batch search failed for {len(chunk)} tickets: {e}")
-            err.__cause__ = e
+        """Search one formed chunk and finish its tickets (no lock held),
+        inside a `sched.flush` trace span with the chunk's `rows`."""
+        with TraceAnnotation("sched.flush", rows=len(chunk)):
+            k = max(t.k for t in chunk)
+            try:
+                ids, scores = self._search([t.text for t in chunk], k)
+            except Exception as e:  # noqa: BLE001 - converted to per-ticket errors
+                err = SchedulerError(
+                    f"batch search failed for {len(chunk)} tickets: {e}"
+                )
+                err.__cause__ = e
+                with self._cv:
+                    self.n_failed += len(chunk)
+                for t in chunk:
+                    t._finish(error=err)
+                if raise_errors:
+                    raise err
+                return 0
+            ids = np.asarray(ids)
+            scores = np.asarray(scores)
+            now = self._clock()
             with self._cv:
-                self.n_failed += len(chunk)
-            for t in chunk:
-                t._finish(error=err)
-            if raise_errors:
-                raise err
-            return 0
-        ids = np.asarray(ids)
-        scores = np.asarray(scores)
-        now = self._clock()
-        with self._cv:
-            seq = self.n_flushes
-            self.n_flushes += 1
-            self.n_served += len(chunk)
-            n = len(chunk)
-            self._batch_size_counts[n] = self._batch_size_counts.get(n, 0) + 1
-        for row, t in enumerate(chunk):
-            t.doc_ids = ids[row, : t.k]
-            t.doc_scores = scores[row, : t.k]
-            t.wait_s = now - t.submit_time
-            t.flush_seq = seq
-            t.batch_size = len(chunk)
-            t._finish()
-        return len(chunk)
+                seq = self.n_flushes
+                self.n_flushes += 1
+                self.n_served += len(chunk)
+                n = len(chunk)
+                self._batch_size_counts[n] = self._batch_size_counts.get(n, 0) + 1
+            for row, t in enumerate(chunk):
+                t.doc_ids = ids[row, : t.k]
+                t.doc_scores = scores[row, : t.k]
+                t.wait_s = now - t.submit_time
+                t.flush_seq = seq
+                t.batch_size = len(chunk)
+                t._finish()
+            return len(chunk)
 
     # ---------------------------------------------------- manual serving
     def poll(self) -> int:

@@ -73,6 +73,17 @@ turns), while manual mode exposes `step()` — admit + one decode step — so
 tests drive admission/retirement deterministically on a fake clock with zero
 sleeps and zero threads.
 
+The engine writes host spans into the JAX profiler's trace at its phase
+boundaries (`jax.profiler.TraceAnnotation`; about a microsecond each
+when no trace is recording): `engine.step` around one step, with args
+`prefill_chunks` and `decode_rows`, and inside it `engine.admit`,
+`engine.prefill` (args `req`, the ticket's `request_id`, and `pos`),
+`engine.decode` (host prep and dispatch), `engine.sample` (the blocking
+copy of the next tokens) and `engine.emit`; the background loop adds
+`engine.idle` while it waits for work. A request's lifetime crosses
+threads, so it is stamped on its ticket (`queue_s`, `first_token_s`,
+`wait_s`) rather than spanned.
+
 Greedy decoding is row-independent in every model here (attention, SSM scan
 and dense MLPs act per batch row), so for fixed prompts the emitted tokens
 are token-for-token identical to per-query `GenerationEngine.generate` —
@@ -97,6 +108,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.model_api import supports_paged_kv
 
@@ -120,9 +132,12 @@ class GenerationTicket:
     """Future-style handle for one generation request.
 
     Filled in by the engine as decoding progresses: `tokens` grows one id
-    per emitted token, `first_token_s` is the submit->first-token latency
-    (TTFT) and `wait_s` the submit->finish latency, both on the engine's
-    clock. `slot` is the decode slot the request occupied. `priority`
+    per emitted token, `queue_s` is the submit->first-admission wait for
+    a slot, `first_token_s` the submit->first-token latency (TTFT) and
+    `wait_s` the submit->finish latency, all on the engine's clock.
+    `request_id` is the engine's submission counter, carried as the `req`
+    argument of the request's `engine.prefill` trace spans. `slot` is the
+    decode slot the request occupied. `priority`
     orders admission and shields the request from preemption
     (`n_preempted` counts how often it was preempted; TTFT/e2e stamps
     span the whole request, preemptions included).
@@ -136,6 +151,9 @@ class GenerationTicket:
         self.tenant = tenant
         self.priority = priority
         self.submit_time = engine._clock()
+        self.request_id: Optional[int] = None
+        # set once, at the first admission: a preempted request keeps it
+        self.queue_s: Optional[float] = None
         self.first_token_s: Optional[float] = None
         self.wait_s: Optional[float] = None
         self.slot: Optional[int] = None
@@ -479,6 +497,7 @@ class ContinuousBatchingEngine:
         self._emitted = np.zeros((n_slots,), np.int64)
         self._prefills: dict[int, _Prefill] = {}  # slot -> chunked prefill
         self._waiting: deque[GenerationTicket] = deque()
+        self._request_ids = itertools.count()
         self._cv = threading.Condition()
         # serializes step() bodies: several threads may drive a manual-mode
         # engine via ticket.result()/token_stream() at once, and the cache
@@ -655,12 +674,14 @@ class ContinuousBatchingEngine:
 
     def _sample(self, logits: jax.Array) -> np.ndarray:
         """(b, V) -> (b,) int32 next tokens."""
-        if self.temperature <= 0:
-            return np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        self._key, sub = jax.random.split(self._key)
-        return np.asarray(
-            jax.random.categorical(sub, logits / self.temperature, axis=-1),
-            np.int32)
+        with TraceAnnotation("engine.sample"):
+            if self.temperature <= 0:
+                return np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            self._key, sub = jax.random.split(self._key)
+            return np.asarray(
+                jax.random.categorical(sub, logits / self.temperature,
+                                       axis=-1),
+                np.int32)
 
     # --------------------------------------------------------------- submit
     def submit(
@@ -722,6 +743,7 @@ class ContinuousBatchingEngine:
         with self._cv:
             if self._closed:
                 raise SchedulerError("engine is closed")
+            t.request_id = next(self._request_ids)
             self._waiting.append(t)
             self._cv.notify_all()
         return t
@@ -893,6 +915,7 @@ class ContinuousBatchingEngine:
                 slot = free[0]
                 # reserve while prefilling outside the lock
                 self._slots[slot] = ticket
+                self._stamp_queue_locked(ticket)
             try:
                 logits, caches1 = self._prefill_one(ticket.prompt)
                 self._caches = self._write_slot(self._caches, caches1,
@@ -909,26 +932,33 @@ class ContinuousBatchingEngine:
             ticket.slot = slot
             emitted += self._emit_first_token(slot, ticket, tok)
 
+    def _stamp_queue_locked(self, ticket: GenerationTicket) -> None:
+        """Stamp the submit->first-admission wait; a preempted ticket
+        re-admitted later keeps its first stamp."""
+        if ticket.queue_s is None:
+            ticket.queue_s = self._clock() - ticket.submit_time
+
     def _emit_first_token(self, slot: int, ticket: GenerationTicket,
                           tok: int) -> int:
         """Shared post-prefill bookkeeping: emit the first token and
         either retire immediately or enter the decode rotation."""
-        ticket._emit(tok)
-        with self._cv:
-            self.n_prefills += 1
-            self.n_tokens += 1
-            # len(tokens), not 1: a resumed sequence re-enters here with
-            # its pre-preemption output already emitted
-            if (self.eos_id is not None and tok == self.eos_id) \
-                    or len(ticket.tokens) >= ticket.max_new_tokens:
-                self._retire_locked(slot)
-                finish = True
-            else:
-                self._cur[slot, 0] = tok
-                self._emitted[slot] = len(ticket.tokens)
-                finish = False
-        if finish:
-            ticket._finish()
+        with TraceAnnotation("engine.emit"):
+            ticket._emit(tok)
+            with self._cv:
+                self.n_prefills += 1
+                self.n_tokens += 1
+                # len(tokens), not 1: a resumed sequence re-enters here
+                # with its pre-preemption output already emitted
+                if (self.eos_id is not None and tok == self.eos_id) \
+                        or len(ticket.tokens) >= ticket.max_new_tokens:
+                    self._retire_locked(slot)
+                    finish = True
+                else:
+                    self._cur[slot, 0] = tok
+                    self._emitted[slot] = len(ticket.tokens)
+                    finish = False
+            if finish:
+                ticket._finish()
         return 1
 
     # ----------------------------------------------------- paged admission
@@ -1002,6 +1032,7 @@ class ContinuousBatchingEngine:
                     continue
                 slot = free[0]
                 self._slots[slot] = ticket
+                self._stamp_queue_locked(ticket)
                 if ticket._resume_prompt is not None:
                     self.n_resumes += 1
             if self._kv_paged:
@@ -1038,7 +1069,9 @@ class ContinuousBatchingEngine:
             pre = self._prefills[slot]
             ticket = pre.ticket
             try:
-                done, logits = self._prefill_chunk_once(slot, pre)
+                with TraceAnnotation("engine.prefill", req=ticket.request_id,
+                                     pos=pre.pos):
+                    done, logits = self._prefill_chunk_once(slot, pre)
                 tok = int(self._sample(logits)[0]) if done else None
             except Exception as e:  # noqa: BLE001 - fail just this ticket
                 err = SchedulerError(f"chunked prefill failed: {e}")
@@ -1120,63 +1153,65 @@ class ContinuousBatchingEngine:
         log2(n_slots) compiled decode shapes. Slot-resident caches are
         positional, so that mode always decodes the full width.
         """
-        with self._cv:
-            active = [(i, t) for i, t in enumerate(self._slots)
-                      if t is not None and i not in self._prefills]
-            if not active:
-                return 0
-            cur = self._cur.copy()
-        if self._kv_paged:
-            idx = [i for i, _ in active]
-            for i in idx:
-                # lazy append: take a block only when the next position
-                # crosses into one (guaranteed by the reservation); then
-                # detach any block a later prefix hit is still sharing
-                # (the mid-decode divergence half of copy-on-write)
-                li = int(self._lengths[i])
-                self._pcm.ensure(i, li + 1)
-                self._cow_barrier(i, li, li + 1)
-            width = min(pow2_at_least(len(idx)), self.n_slots)
-            tables = self._pcm.tables(idx + [None] * (width - len(idx)))
-            lengths = np.zeros((width,), np.int32)
-            lengths[: len(idx)] = self._lengths[idx]
-            toks = np.full((width, 1), self._pad_id, np.int32)
-            toks[: len(idx), 0] = cur[idx, 0]
-            n_valid = np.zeros((width,), np.int32)
-            n_valid[: len(idx)] = 1
-            logits, self._pools = self._paged_step(
-                self.params, self._pools, jnp.asarray(tables),
-                jnp.asarray(lengths), jnp.asarray(toks),
-                jnp.asarray(n_valid))
-        else:
-            logits, self._caches = self._decode(
-                self.params, self._caches, jnp.asarray(cur))
+        with TraceAnnotation("engine.decode"):
+            with self._cv:
+                active = [(i, t) for i, t in enumerate(self._slots)
+                          if t is not None and i not in self._prefills]
+                if not active:
+                    return 0
+                cur = self._cur.copy()
+            if self._kv_paged:
+                idx = [i for i, _ in active]
+                for i in idx:
+                    # lazy append: take a block only when the next position
+                    # crosses into one (guaranteed by the reservation); then
+                    # detach any block a later prefix hit is still sharing
+                    # (the mid-decode divergence half of copy-on-write)
+                    li = int(self._lengths[i])
+                    self._pcm.ensure(i, li + 1)
+                    self._cow_barrier(i, li, li + 1)
+                width = min(pow2_at_least(len(idx)), self.n_slots)
+                tables = self._pcm.tables(idx + [None] * (width - len(idx)))
+                lengths = np.zeros((width,), np.int32)
+                lengths[: len(idx)] = self._lengths[idx]
+                toks = np.full((width, 1), self._pad_id, np.int32)
+                toks[: len(idx), 0] = cur[idx, 0]
+                n_valid = np.zeros((width,), np.int32)
+                n_valid[: len(idx)] = 1
+                logits, self._pools = self._paged_step(
+                    self.params, self._pools, jnp.asarray(tables),
+                    jnp.asarray(lengths), jnp.asarray(toks),
+                    jnp.asarray(n_valid))
+            else:
+                logits, self._caches = self._decode(
+                    self.params, self._caches, jnp.asarray(cur))
         nxt = self._sample(logits)
-        finished: list[GenerationTicket] = []
-        emitted = 0
-        with self._cv:
-            self.n_decode_steps += 1
-            n_active = len(active)
-            self._occupancy_counts[n_active] = \
-                self._occupancy_counts.get(n_active, 0) + 1
-            for row, (slot, ticket) in enumerate(active):
-                if self._slots[slot] is not ticket:  # failed concurrently
-                    continue
-                if self._kv_paged:
-                    self._lengths[slot] += 1
-                tok = int(nxt[row if self._kv_paged else slot])
-                ticket._emit(tok)
-                emitted += 1
-                self.n_tokens += 1
-                self._emitted[slot] += 1
-                if (self.eos_id is not None and tok == self.eos_id) or \
-                        self._emitted[slot] >= ticket.max_new_tokens:
-                    self._retire_locked(slot)
-                    finished.append(ticket)
-                else:
-                    self._cur[slot, 0] = tok
-        for ticket in finished:
-            ticket._finish()
+        with TraceAnnotation("engine.emit"):
+            finished: list[GenerationTicket] = []
+            emitted = 0
+            with self._cv:
+                self.n_decode_steps += 1
+                n_active = len(active)
+                self._occupancy_counts[n_active] = \
+                    self._occupancy_counts.get(n_active, 0) + 1
+                for row, (slot, ticket) in enumerate(active):
+                    if self._slots[slot] is not ticket:  # failed concurrently
+                        continue
+                    if self._kv_paged:
+                        self._lengths[slot] += 1
+                    tok = int(nxt[row if self._kv_paged else slot])
+                    ticket._emit(tok)
+                    emitted += 1
+                    self.n_tokens += 1
+                    self._emitted[slot] += 1
+                    if (self.eos_id is not None and tok == self.eos_id) or \
+                            self._emitted[slot] >= ticket.max_new_tokens:
+                        self._retire_locked(slot)
+                        finished.append(ticket)
+                    else:
+                        self._cur[slot, 0] = tok
+            for ticket in finished:
+                ticket._finish()
         return emitted
 
     # ------------------------------------------------- priority preemption
@@ -1310,17 +1345,21 @@ class ContinuousBatchingEngine:
         pieces processed. 0 means the engine is idle. Manual-mode entry
         point; the background loop calls the same path.
         """
-        with self._step_lock:
+        with self._step_lock, TraceAnnotation("engine.step") as span:
             if self.paged:
-                self._admit_paged()
-                work = self._advance_prefills()
+                with TraceAnnotation("engine.admit"):
+                    self._admit_paged()
+                chunks = self._advance_prefills()
             else:
-                work = self._admit()
+                with TraceAnnotation("engine.admit"):
+                    chunks = self._admit()  # whole-prompt prefills
             with self._cv:
                 self.peak_active = max(
                     self.peak_active,
                     sum(t is not None for t in self._slots))
-            return work + self._decode_once()
+            rows = self._decode_once()
+            span.set_metadata(prefill_chunks=chunks, decode_rows=rows)
+            return chunks + rows
 
     def run_until_drained(self, max_steps: Optional[int] = None) -> int:
         """step() until no work remains; returns total work units."""
@@ -1343,7 +1382,8 @@ class ContinuousBatchingEngine:
             with self._cv:
                 while not self._closed and not self._waiting \
                         and all(t is None for t in self._slots):
-                    self._cv.wait()
+                    with TraceAnnotation("engine.idle"):
+                        self._cv.wait()
                 if self._closed:
                     idle = not self._waiting and \
                         all(t is None for t in self._slots)

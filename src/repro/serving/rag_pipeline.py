@@ -60,6 +60,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.device_physics import DriftConfig
 from repro.core.recalibration import (
@@ -201,14 +202,18 @@ class RagPipeline:
         """Embed + search a whole batch as one (b, dim) call.
 
         Returns (ids (b, k) int32, scores (b, k) fp32). This is the unit
-        the BatchScheduler flushes."""
-        q = jnp.asarray(self.embedder.embed(list(texts)))
-        res = self.index.search(q, k=k, key=key)
-        if self.recal_controller is not None:
-            # Cheap when no detection window has filled; fires online
-            # per-shard re-extraction + re-encode when one has drifted.
-            self.recal_controller.poll()
-        return np.asarray(res.indices), np.asarray(res.scores)
+        the BatchScheduler flushes. Traced as `rag.embed` (the host
+        embedder) and `rag.search` (the index search up to its results on
+        the host)."""
+        with TraceAnnotation("rag.embed"):
+            emb = self.embedder.embed(list(texts))
+        with TraceAnnotation("rag.search"):
+            res = self.index.search(jnp.asarray(emb), k=k, key=key)
+            if self.recal_controller is not None:
+                # Cheap when no detection window has filled; fires online
+                # per-shard re-extraction + re-encode when one has drifted.
+                self.recal_controller.poll()
+            return np.asarray(res.indices), np.asarray(res.scores)
 
     def retrieval_stats(self) -> dict:
         """Per-shard error/recal counters + the controller's view.
@@ -353,14 +358,15 @@ class RagPipeline:
         and share their context KV under `prefix_sharing`. When
         `max_prompt_len` truncation cuts into the header (the template
         keeps the prompt TAIL), the surviving header is still shared;
-        0 means nothing shareable survived.
+        0 means nothing shareable survived. Traced as `rag.prompt`.
         """
-        prompt = self.tokenizer.encode_rag_prompt(
-            text, list(retrieved_texts), self.max_prompt_len)
-        n_query = len(self.tokenizer.encode(text, bos=False))
-        prefix_len = max(len(prompt) - n_query, 0)
-        vocab = self.engine.model.cfg.vocab_size
-        return [t % vocab for t in prompt], prefix_len
+        with TraceAnnotation("rag.prompt"):
+            prompt = self.tokenizer.encode_rag_prompt(
+                text, list(retrieved_texts), self.max_prompt_len)
+            n_query = len(self.tokenizer.encode(text, bos=False))
+            prefix_len = max(len(prompt) - n_query, 0)
+            vocab = self.engine.model.cfg.vocab_size
+            return [t % vocab for t in prompt], prefix_len
 
     def query_stream(self, requests, k: int = 3, max_batch: int = 32,
                      max_wait_ms: float = 5.0,
