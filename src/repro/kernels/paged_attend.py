@@ -30,13 +30,19 @@ The per-chunk `(acc, m, denom)` partials are reduced in a second pass
 online-softmax rescale, so long contexts parallelize over the KV axis
 instead of serializing per row.
 
-Layout notes: the block table and per-row length/n_valid scalars are
-scalar-prefetched into SMEM; the K/V pools stay unblocked in HBM
-(`pl.ANY`) and move only by DMA. Scores are one 2-D matmul of every
-query head against every (token, KV head) row of the chunk, with the
-rows of other KV heads masked out: that keeps each dot two-dimensional,
-which is what Mosaic lowers, at `n_kv_heads` times the score FLOPs of a
-per-head loop — small next to the bytes a decode step moves.
+Layout notes: the block table, the per-row length/n_valid scalars and
+the layer index are scalar-prefetched into SMEM; the K/V pools stay
+unblocked in HBM (`pl.ANY`) and move only by DMA. The pools may be one
+layer's `(n_blocks, bs, kh, hd)` or the whole stack's
+`(L, n_blocks, bs, kh, hd)` with a layer index: a layer scan then hands
+the kernel the stacked pools it carries, the DMAs address
+`pool[layer, block]`, and the aliased stack is updated in place instead
+of being sliced out per layer and written back. Scores are one 2-D
+matmul of every query head against every (token, KV head) row of the
+chunk, with the rows of other KV heads masked out: that keeps each dot
+two-dimensional, which is what Mosaic lowers, at `n_kv_heads` times the
+score FLOPs of a per-head loop — small next to the bytes a decode step
+moves.
 """
 from __future__ import annotations
 
@@ -59,7 +65,7 @@ CHUNK_TOKENS = 128
 VMEM_LIMIT_BYTES = 64 * 2**20
 
 
-def _paged_attend_kernel(table_ref, len_ref, nv_ref,
+def _paged_attend_kernel(table_ref, len_ref, nv_ref, layer_ref,
                          q_ref, kn_ref, vn_ref, kpool_ref, vpool_ref,
                          acc_ref, m_ref, den_ref, kout_ref, vout_ref,
                          k_buf, v_buf, sem,
@@ -74,15 +80,16 @@ def _paged_attend_kernel(table_ref, len_ref, nv_ref,
     row0 = i * table_width
     length = len_ref[i]
     n_valid = nv_ref[i]
+    layer = layer_ref[0]
 
     # -- gather this chunk's physical blocks through the block table.
     copies = []
     for c in range(cb):
         phys = table_ref[row0 + j * cb + c]
-        copies.append(pltpu.make_async_copy(kpool_ref.at[phys], k_buf.at[c],
-                                            sem))
-        copies.append(pltpu.make_async_copy(vpool_ref.at[phys], v_buf.at[c],
-                                            sem))
+        copies.append(pltpu.make_async_copy(kpool_ref.at[layer, phys],
+                                            k_buf.at[c], sem))
+        copies.append(pltpu.make_async_copy(vpool_ref.at[layer, phys],
+                                            v_buf.at[c], sem))
     for cp in copies:
         cp.start()
     for cp in copies:
@@ -106,8 +113,8 @@ def _paged_attend_kernel(table_ref, len_ref, nv_ref,
             k_buf[c, off] = kn_ref[0, ti]
             v_buf[c, off] = vn_ref[0, ti]
             for src, dst in ((kn_ref, kout_ref), (vn_ref, vout_ref)):
-                cp = pltpu.make_async_copy(src.at[0, ti], dst.at[phys, off],
-                                           sem)
+                cp = pltpu.make_async_copy(src.at[0, ti],
+                                           dst.at[layer, phys, off], sem)
                 cp.start()
                 cp.wait()
 
@@ -143,20 +150,30 @@ def paged_attend_fused(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                        block_table: jax.Array, length: jax.Array,
                        n_valid: jax.Array,
                        chunk_blocks: Optional[int] = None,
-                       interpret: Optional[bool] = None):
+                       interpret: Optional[bool] = None,
+                       layer: Optional[jax.Array] = None):
     """Fused scatter + block-table attention over `t` new positions.
 
     q (b, t, h, hd) post-RoPE queries; k_new/v_new (b, t, kh, hd) the new
     K/V for logical positions `length[b] .. length[b] + t - 1` (entries
     past `n_valid[b]` are padding and are neither written nor attended);
-    pools (n_blocks, block_size, kh, hd); block_table (b, max_blocks)
-    int32. Returns (out (b, t, h, hd) in q.dtype, k_pool', v_pool') with
-    identical semantics to the gather path in `models.attention`, except
-    invalid lanes skip the scatter entirely instead of writing the
-    NULL_BLOCK scratch (both leave scratch content unspecified).
+    pools (n_blocks, block_size, kh, hd), or the stacked
+    (L, n_blocks, block_size, kh, hd) with `layer` the int32 scalar that
+    picks the layer attended and written (the other layers pass through
+    untouched); block_table (b, max_blocks) int32. Returns (out
+    (b, t, h, hd) in q.dtype, k_pool', v_pool') with identical semantics
+    to the gather path in `models.attention`, except invalid lanes skip
+    the scatter entirely instead of writing the NULL_BLOCK scratch (both
+    leave scratch content unspecified).
     """
+    if layer is None:
+        out, kp, vp = paged_attend_fused(
+            q, k_new, v_new, k_pool[None], v_pool[None], block_table,
+            length, n_valid, chunk_blocks=chunk_blocks, interpret=interpret,
+            layer=jnp.int32(0))
+        return out, kp[0], vp[0]
     b, t, h, hd = q.shape
-    _, bs, kh, _ = k_pool.shape
+    _, _, bs, kh, _ = k_pool.shape
     mb = block_table.shape[1]
     cb = min(mb, chunk_blocks or max(1, CHUNK_TOKENS // bs))
     # Pad the table to a chunk multiple with NULL_BLOCK: the padded
@@ -171,7 +188,7 @@ def paged_attend_fused(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     new_block = lambda i, j, *_: (i, 0, 0, 0)       # noqa: E731
     part_block = lambda i, j, *_: (i, j, 0, 0)      # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, nc),
         in_specs=[
             pl.BlockSpec((1, t * h, hd), row_block),
@@ -205,12 +222,13 @@ def paged_attend_fused(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
             jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
             jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
         ],
-        input_output_aliases={6: 3, 7: 4},
+        input_output_aliases={7: 3, 8: 4},
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=resolve_interpret(interpret),
     )(block_table.reshape(-1).astype(jnp.int32), length.astype(jnp.int32),
-      n_valid.astype(jnp.int32), q.reshape(b, t * h, hd),
+      n_valid.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q.reshape(b, t * h, hd),
       k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype), k_pool, v_pool)
 
     # -- second pass: flash-decoding combine of the per-chunk partials.

@@ -285,7 +285,9 @@ class PagedKVCache(NamedTuple):
     point every table entry at it so their writes never touch live data.
     Allocation/free/backpressure bookkeeping is host-side
     (`serving.paged_cache.PagedCacheManager`); this view is what the
-    jitted step consumes.
+    jitted step consumes. A layer scan carries the stacked
+    `(L, n_blocks, block_size, kh, hd)` pools here instead and names its
+    layer to `paged_attend`.
     """
 
     k_pool: jax.Array       # (n_blocks, block_size, kh, hd)
@@ -295,11 +297,12 @@ class PagedKVCache(NamedTuple):
 
 
 def _paged_write(pool: jax.Array, new: jax.Array, cache: PagedKVCache,
-                 n_valid: jax.Array) -> jax.Array:
-    """Scatter `new` (b, t, kh, hd) into the pool at each row's next
-    `n_valid[b]` logical positions; invalid lanes land in NULL_BLOCK."""
+                 n_valid: jax.Array, layer: jax.Array) -> jax.Array:
+    """Scatter `new` (b, t, kh, hd) into layer `layer` of the stacked
+    pool at each row's next `n_valid[b]` logical positions; invalid
+    lanes land in NULL_BLOCK."""
     b, t = new.shape[:2]
-    block_size = pool.shape[1]
+    block_size = pool.shape[2]
     mb = cache.block_table.shape[1]
     pos = cache.length[:, None] + jnp.arange(t)[None, :]          # (b, t)
     valid = jnp.arange(t)[None, :] < n_valid[:, None]             # (b, t)
@@ -307,14 +310,15 @@ def _paged_write(pool: jax.Array, new: jax.Array, cache: PagedKVCache,
         cache.block_table, jnp.clip(pos // block_size, 0, mb - 1), axis=1)
     blk = jnp.where(valid, blk, 0)   # NULL_BLOCK scratch
     off = jnp.where(valid, pos % block_size, 0)
-    return pool.at[blk, off].set(new.astype(pool.dtype))
+    return pool.at[layer, blk, off].set(new.astype(pool.dtype))
 
 
 def paged_attend(cfg: ModelConfig, params: dict, x: jax.Array,
                  cache: PagedKVCache, angles: Optional[jax.Array],
                  n_valid: jax.Array,
                  h: Optional[int] = None, kh: Optional[int] = None,
-                 paged_kernel: Optional[bool] = None):
+                 paged_kernel: Optional[bool] = None,
+                 layer: Optional[jax.Array] = None):
     """Block-table attention over `t` new positions per row.
 
     x (b, t, d) holds each row's next `n_valid[b] <= t` tokens starting
@@ -326,6 +330,12 @@ def paged_attend(cfg: ModelConfig, params: dict, x: jax.Array,
     j <= length + i. Returns (y (b, t, d), k_pool', v_pool'); rows
     beyond n_valid produce garbage outputs the caller must ignore (the
     pools stay clean outside the scratch block).
+
+    With `layer` (an int32 scalar) the cache holds the stacked
+    `(L, n_blocks, block_size, kh, hd)` pools: this layer's K/V are read
+    and written at `pool[layer]`, the other layers come back untouched,
+    and nothing copies a layer out of the stack, so a layer scan that
+    carries the pools updates them in place.
 
     Two dispatch paths, selected by `paged_kernel` (falling back to
     `cfg.paged_kernel`):
@@ -341,6 +351,13 @@ def paged_attend(cfg: ModelConfig, params: dict, x: jax.Array,
     """
     from . import rope as rope_mod
 
+    if layer is None:
+        stacked = cache._replace(k_pool=cache.k_pool[None],
+                                 v_pool=cache.v_pool[None])
+        y, k_pool, v_pool = paged_attend(
+            cfg, params, x, stacked, angles, n_valid, h, kh, paged_kernel,
+            layer=jnp.int32(0))
+        return y, k_pool[0], v_pool[0]
     h = h or cfg.effective_n_heads
     kh = kh or cfg.n_kv_heads
     hd = params["wq"].shape[1] // h
@@ -356,16 +373,16 @@ def paged_attend(cfg: ModelConfig, params: dict, x: jax.Array,
 
         out, k_pool, v_pool = paged_attend_fused(
             q, k_new, v_new, cache.k_pool, cache.v_pool,
-            cache.block_table, cache.length, n_valid)
+            cache.block_table, cache.length, n_valid, layer=layer)
         y = out.reshape(b, t, h * hd).astype(cdt) @ params["wo"].astype(cdt)
         return y, k_pool, v_pool
-    k_pool = _paged_write(cache.k_pool, k_new, cache, n_valid)
-    v_pool = _paged_write(cache.v_pool, v_new, cache, n_valid)
-    block_size = k_pool.shape[1]
+    k_pool = _paged_write(cache.k_pool, k_new, cache, n_valid, layer)
+    v_pool = _paged_write(cache.v_pool, v_new, cache, n_valid, layer)
+    block_size = k_pool.shape[2]
     mb = cache.block_table.shape[1]
     S = mb * block_size
-    k = k_pool[cache.block_table].reshape(b, S, kh, hd)
-    v = v_pool[cache.block_table].reshape(b, S, kh, hd)
+    k = k_pool[layer, cache.block_table].reshape(b, S, kh, hd)
+    v = v_pool[layer, cache.block_table].reshape(b, S, kh, hd)
     g = h // kh
     qg = q.reshape(b, t, kh, g, hd) * hd**-0.5
     s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,

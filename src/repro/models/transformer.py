@@ -298,6 +298,10 @@ class DecoderLM:
         logits are garbage the caller ignores. `paged_kernel` selects the
         fused Pallas path in `attention.paged_attend` (None defers to
         `cfg.paged_kernel`).
+
+        The layer loop carries the whole pools and hands each layer its
+        index; both paths write only that layer's new positions, so
+        under a jit that donates `pools` no program copies a pool.
         """
         cfg = self.cfg
         x = layers.embed_tokens(cfg, params["embedding"], tokens)
@@ -308,19 +312,21 @@ class DecoderLM:
                 positions = jnp.broadcast_to(positions[None], (3, b, t))
         angles = self._angles(positions, b, t)
 
-        def scan_fn(x, inp):
-            p, kp, vp = inp
+        def scan_fn(carry, inp):
+            x, kp, vp = carry
+            p, layer = inp
             cache = attention.PagedKVCache(
                 k_pool=kp, v_pool=vp, block_table=block_tables,
                 length=lengths)
             h = layers.apply_norm(cfg, p["attn_norm"], x)
-            y, kp2, vp2 = attention.paged_attend(
+            y, kp, vp = attention.paged_attend(
                 cfg, p["attn"], h, cache, angles, n_valid,
-                paged_kernel=paged_kernel)
-            return self._block_join(p, x, h, y), (kp2, vp2)
+                paged_kernel=paged_kernel, layer=layer)
+            return (self._block_join(p, x, h, y), kp, vp), None
 
-        x, (k_new, v_new) = jax.lax.scan(
-            scan_fn, x, (params["blocks"], pools.k_pool, pools.v_pool),
+        (x, k_new, v_new), _ = jax.lax.scan(
+            scan_fn, (x, pools.k_pool, pools.v_pool),
+            (params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
             unroll=cfg.scan_unroll,
         )
         x = layers.apply_norm(cfg, params["final_norm"], x)

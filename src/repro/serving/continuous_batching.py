@@ -84,6 +84,11 @@ copy of the next tokens) and `engine.emit`; the background loop adds
 threads, so it is stamped on its ticket (`queue_s`, `first_token_s`,
 `wait_s`) rather than spanned.
 
+The KV pools are updated in place: every jit that takes them
+(`_paged_step`, `_copy_block`, `_write_block`) donates them, and the
+engine keeps no reference to pools it has handed over — `self._pools`
+is replaced by each call's result under the step lock.
+
 Greedy decoding is row-independent in every model here (attention, SSM scan
 and dense MLPs act per batch row), so for fixed prompts the emitted tokens
 are token-for-token identical to per-query `GenerationEngine.generate` —
@@ -126,6 +131,22 @@ def _single_device(tree):
         if isinstance(leaf, jax.Array):
             devices |= leaf.devices()
     return devices.pop() if len(devices) == 1 else None
+
+
+def paged_step_program(model, paged_kernel: Optional[bool] = None):
+    """The engine's jitted paged step, `(params, pools, tables, lengths,
+    tokens, n_valid) -> (logits, pools')`, donating `pools`: with the
+    model's layer loop carrying the pools, no program copies a pool.
+    `paged_kernel` None defers to the model (`cfg.paged_kernel`) and
+    keeps duck-typed models whose `paged_step` lacks the knob working."""
+    if paged_kernel is None:
+        def step(p, pools, tbl, ln, tok, nv):
+            return model.paged_step(p, pools, tbl, ln, tok, nv)
+    else:
+        def step(p, pools, tbl, ln, tok, nv):
+            return model.paged_step(p, pools, tbl, ln, tok, nv,
+                                    paged_kernel=paged_kernel)
+    return jax.jit(step, donate_argnums=(1,))
 
 
 class GenerationTicket:
@@ -469,20 +490,12 @@ class ContinuousBatchingEngine:
             with jax.default_device(self.device):
                 self._pools = model.init_paged_caches(n_blocks, block_size)
             self.paged_kernel = paged_kernel
-            if paged_kernel is None:
-                # model decides (cfg.paged_kernel); also keeps duck-typed
-                # models whose paged_step lacks the knob working
-                self._paged_step = jax.jit(
-                    lambda p, pools, tbl, ln, tok, nv: model.paged_step(
-                        p, pools, tbl, ln, tok, nv))
-            else:
-                self._paged_step = jax.jit(
-                    lambda p, pools, tbl, ln, tok, nv: model.paged_step(
-                        p, pools, tbl, ln, tok, nv,
-                        paged_kernel=paged_kernel))
+            self._paged_step = paged_step_program(model, paged_kernel)
             self._pool_block_axes = self._detect_block_axes(block_size)
-            self._copy_block = jax.jit(self._copy_block_impl)
-            self._write_block = jax.jit(self._write_block_impl)
+            self._copy_block = jax.jit(self._copy_block_impl,
+                                       donate_argnums=(0,))
+            self._write_block = jax.jit(self._write_block_impl,
+                                        donate_argnums=(0,))
             self._lengths = np.zeros((n_slots,), np.int64)
             self._caches = None
         else:

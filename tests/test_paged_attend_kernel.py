@@ -208,6 +208,61 @@ def test_edge_null_tail_garbage_masked(rng, mini):
     assert np.all(np.abs(np.asarray(k[0])) < 1e4)
 
 
+# ------------------------------------------------ stacked-pool (L, ...) form
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("t,lengths,n_valid,mb", [
+    (1, [5, 8, 0, 23], [1, 1, 0, 1], 7),   # decode, several rows
+    (32, [6], [32], 12),                   # one prefill chunk
+], ids=["decode", "prefill"])
+def test_stacked_pools_match_one_layer_form(rng, mini, layer, t, lengths,
+                                            n_valid, mb):
+    """The kernel and the gather path given the layer stack and a layer
+    index attend and write exactly what they do given that layer alone,
+    and leave every other layer bit-identical."""
+    cfg, params = mini
+    b, n_layers = len(lengths), 3
+    x, cache, angles, nv = _mk_case(rng, cfg, b=b, t=t, bs=4, mb=mb,
+                                    lengths=lengths, n_valid=n_valid)
+    shape = (n_layers,) + cache.k_pool.shape
+    k_stack = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    v_stack = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    k_stack = k_stack.at[layer].set(cache.k_pool)
+    v_stack = v_stack.at[layer].set(cache.v_pool)
+    stacked = cache._replace(k_pool=k_stack, v_pool=v_stack)
+    li = jnp.int32(layer)
+    others = [i for i in range(n_layers) if i != layer]
+
+    q, kn, vn = A._project_qkv(cfg, params, x, cfg.n_heads, cfg.n_kv_heads,
+                               cfg.resolved_head_dim)
+    q = rope.apply_rotary(q, angles)
+    kn = rope.apply_rotary(kn, angles)
+    args = (cache.block_table, cache.length, nv)
+    out4, k4, v4 = paged_attend_fused(q, kn, vn, cache.k_pool, cache.v_pool,
+                                      *args)
+    out5, k5, v5 = paged_attend_fused(q, kn, vn, k_stack, v_stack, *args,
+                                      layer=li)
+    np.testing.assert_array_equal(np.asarray(out5), np.asarray(out4))
+    np.testing.assert_array_equal(np.asarray(k5[layer]), np.asarray(k4))
+    np.testing.assert_array_equal(np.asarray(v5[layer]), np.asarray(v4))
+
+    gather = A.paged_attend(cfg, params, x, cache, angles, nv,
+                            paged_kernel=False)
+    kernel = (out5.reshape(b, t, -1) @ params["wo"], k5[layer], v5[layer])
+    _assert_parity(gather, kernel, nv)
+    yg5, kg5, vg5 = A.paged_attend(cfg, params, x, stacked, angles, nv,
+                                   paged_kernel=False, layer=li)
+    np.testing.assert_array_equal(np.asarray(yg5), np.asarray(gather[0]))
+    np.testing.assert_array_equal(np.asarray(kg5[layer])[1:],
+                                  np.asarray(gather[1])[1:])
+    np.testing.assert_array_equal(np.asarray(vg5[layer])[1:],
+                                  np.asarray(gather[2])[1:])
+    for new_k, new_v in ((k5, v5), (kg5, vg5)):
+        np.testing.assert_array_equal(np.asarray(new_k)[others],
+                                      np.asarray(k_stack)[others])
+        np.testing.assert_array_equal(np.asarray(new_v)[others],
+                                      np.asarray(v_stack)[others])
+
+
 # --------------------------------------------------- engine-level parity
 def test_engine_greedy_parity_kernel_vs_gather():
     """ContinuousBatchingEngine(paged_kernel=True) emits token-for-token

@@ -381,3 +381,62 @@ def test_host_round_trip_bit_identical_fp32_real_model():
         jnp.asarray(att_prompt, jnp.int32)[None],
         max_new_tokens=4, cache_len=48)
     assert np.array_equal(att.result(), np.asarray(ref)[0])
+
+
+# ------------------------------------------ engine: donated pools stay safe
+def test_donated_pools_with_cow_and_swapin_match_unshared_run():
+    """Every program that takes the pools (the step, the CoW block copy,
+    the host-tier swap-in write) donates them. Copy-on-write and a
+    swap-in interleaved with donated steps give the tokens of an
+    unshared, non-retaining engine, and every pool handed to a program
+    is consumed there, never read again."""
+    cfg = dataclasses.replace(
+        get_config("phi4-mini-3.8b", smoke=True), compute_dtype="float32")
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    rng = np.random.default_rng(5)
+    ctx = rng.integers(0, cfg.vocab_size, size=19)  # partial third block
+
+    def after_ctx(n):
+        return np.concatenate([ctx, rng.integers(0, cfg.vocab_size, n)])
+
+    pub, att, late = after_ctx(5), after_ctx(4), after_ctx(3)
+    filler = rng.integers(0, cfg.vocab_size, 36)
+
+    def run(shared):
+        tiers = dict(prefix_sharing=True, retain_blocks=3,
+                     host_blocks=3) if shared else {}
+        eng = ContinuousBatchingEngine(model, params, EngineConfig(
+            n_slots=2, cache_len=48, paged=True, block_size=8, n_blocks=9,
+            prefill_chunk=8, **tiers))
+        handed = []
+        for name, at in (("_paged_step", 1), ("_copy_block", 0),
+                         ("_write_block", 0)):
+            def call(*a, _inner=getattr(eng, name), _name=name, _at=at):
+                handed.append((_name, jax.tree_util.tree_leaves(a[_at])))
+                return _inner(*a)
+            setattr(eng, name, call)
+        first = eng.submit(pub, max_new_tokens=6, prefix_len=19)
+        for _ in range(3):  # 3 chunks: published, still decoding
+            eng.step()
+        tickets = [first, eng.submit(att, max_new_tokens=4, prefix_len=19)]
+        eng.run_until_drained()
+        # 5 blocks against 4 free: evicts the retained ctx to the host
+        tickets.append(eng.submit(filler, max_new_tokens=4))
+        eng.run_until_drained()
+        tickets.append(eng.submit(late, max_new_tokens=4, prefix_len=19))
+        eng.run_until_drained()
+        assert all(leaf.is_deleted() for _, leaves in handed
+                   for leaf in leaves)
+        assert not any(leaf.is_deleted()
+                       for leaf in jax.tree_util.tree_leaves(eng._pools))
+        return ([np.asarray(t.result()) for t in tickets], eng.stats()["pool"],
+                {name for name, _ in handed})
+
+    outs, st, programs = run(shared=True)
+    assert st["n_cow_copies"] >= 1 and st["n_host_hits"] == 1
+    assert programs == {"_paged_step", "_copy_block", "_write_block"}
+    ref_outs, ref_st, _ = run(shared=False)
+    assert ref_st["n_prefix_hits"] == 0
+    for a, b in zip(outs, ref_outs):
+        assert np.array_equal(a, b)

@@ -8,7 +8,15 @@ query / 8 KV heads, head_dim 128, 16-token blocks) for `paged_attend`,
 the paper's retrieval point (8,192 documents at dim 512) for the scoring
 kernels. The topology is described inside a fixture, never at import:
 only one process may load the TPU library at a time.
+
+The engine's paged step program is compiled too, at phi4-mini widths
+with 4 layers and a 1,025-block pool, to show from `memory_analysis()`
+that it updates the KV pools in place: the donated pools alias the
+output and no temp buffer holds even one layer's pool. (XLA:CPU keeps a
+full-size temp for a scatter inside a loop even when donated, so only a
+compile for the TPU can show this.)
 """
+import dataclasses
 import os
 
 import jax
@@ -90,3 +98,46 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     compiled = jax.jit(fn).lower(*make_args(shaped)).compile()
     assert "tpu_custom_call" in compiled.as_text(), \
         f"{name} did not lower to a Mosaic kernel"
+
+
+# phi4-mini's step program with the cell's pool, cut to 4 layers
+STEP_LAYERS, POOL_BLOCKS, TABLE = 4, 1025, 128
+
+
+@pytest.mark.parametrize("paged_kernel", [True, False],
+                         ids=["kernel", "gather"])
+@pytest.mark.parametrize("b,t", [(16, 1), (1, PREFILL_CHUNK)],
+                         ids=["decode", "prefill"])
+def test_paged_step_updates_pools_in_place(one_chip, monkeypatch,
+                                           paged_kernel, b, t):
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serving.continuous_batching import paged_step_program
+
+    # the kernel interprets when the default backend is not a TPU, as it
+    # is here; compile what the chip runs
+    monkeypatch.setattr(paged_attend, "resolve_interpret", lambda _: False)
+    cfg = dataclasses.replace(get_config("phi4-mini-3.8b"),
+                              n_layers=STEP_LAYERS, paged_kernel=paged_kernel)
+    model = build_model(cfg)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(model.init,
+                                                  jax.random.key(0)))
+    pools = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: model.init_paged_caches(POOL_BLOCKS, BS)))
+    i32 = jnp.int32
+    args = (params, pools, on_chip(jax.ShapeDtypeStruct((b, TABLE), i32)),
+            on_chip(jax.ShapeDtypeStruct((b,), i32)),
+            on_chip(jax.ShapeDtypeStruct((b, t), i32)),
+            on_chip(jax.ShapeDtypeStruct((b,), i32)))
+    compiled = paged_step_program(model).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(leaf.size * leaf.dtype.itemsize
+                     for leaf in jax.tree.leaves(pools))
+    one_layer_k = pools.k_pool.size // STEP_LAYERS * pools.k_pool.dtype.itemsize
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < one_layer_k
+    assert ("tpu_custom_call" in compiled.as_text()) == paged_kernel
