@@ -276,7 +276,7 @@ def decode_step(cfg: ModelConfig, params: dict, x: jax.Array,
 
 
 class PagedKVCache(NamedTuple):
-    """Per-layer view of the block-pooled KV cache (serving memory model).
+    """View of the block-pooled KV cache (serving memory model).
 
     Unlike `KVCache`, where row b owns a contiguous (S, kh, hd) region,
     the pool is shared by every sequence: row b's logical positions live
@@ -285,24 +285,24 @@ class PagedKVCache(NamedTuple):
     point every table entry at it so their writes never touch live data.
     Allocation/free/backpressure bookkeeping is host-side
     (`serving.paged_cache.PagedCacheManager`); this view is what the
-    jitted step consumes. A layer scan carries the stacked
-    `(L, n_blocks, block_size, kh, hd)` pools here instead and names its
-    layer to `paged_attend`.
+    jitted step consumes. The pools are every layer's, stacked and
+    head-major, so a (block, head) tile is `(block_size, hd)` whatever
+    the KV-head count; `paged_attend` is told which layer to use.
     """
 
-    k_pool: jax.Array       # (n_blocks, block_size, kh, hd)
-    v_pool: jax.Array       # (n_blocks, block_size, kh, hd)
+    k_pool: jax.Array       # (L, n_blocks, kh, block_size, hd)
+    v_pool: jax.Array       # (L, n_blocks, kh, block_size, hd)
     block_table: jax.Array  # (b, max_blocks) int32 physical block ids
     length: jax.Array       # (b,) int32 — tokens already written
 
 
 def _paged_write(pool: jax.Array, new: jax.Array, cache: PagedKVCache,
                  n_valid: jax.Array, layer: jax.Array) -> jax.Array:
-    """Scatter `new` (b, t, kh, hd) into layer `layer` of the stacked
-    pool at each row's next `n_valid[b]` logical positions; invalid
-    lanes land in NULL_BLOCK."""
+    """Scatter `new` (b, t, kh, hd) into layer `layer` of the stacked,
+    head-major pool at each row's next `n_valid[b]` logical positions;
+    invalid lanes land in NULL_BLOCK."""
     b, t = new.shape[:2]
-    block_size = pool.shape[2]
+    block_size = pool.shape[3]
     mb = cache.block_table.shape[1]
     pos = cache.length[:, None] + jnp.arange(t)[None, :]          # (b, t)
     valid = jnp.arange(t)[None, :] < n_valid[:, None]             # (b, t)
@@ -310,16 +310,21 @@ def _paged_write(pool: jax.Array, new: jax.Array, cache: PagedKVCache,
         cache.block_table, jnp.clip(pos // block_size, 0, mb - 1), axis=1)
     blk = jnp.where(valid, blk, 0)   # NULL_BLOCK scratch
     off = jnp.where(valid, pos % block_size, 0)
-    return pool.at[layer, blk, off].set(new.astype(pool.dtype))
+    # every axis but hd indexed by an array: with a slice between the
+    # index arrays XLA's TPU scatter copies the whole donated pool
+    heads = jnp.arange(pool.shape[2])[None, None, :]
+    return pool.at[layer, blk[..., None], heads, off[..., None]].set(
+        new.astype(pool.dtype))
 
 
 def paged_attend(cfg: ModelConfig, params: dict, x: jax.Array,
                  cache: PagedKVCache, angles: Optional[jax.Array],
                  n_valid: jax.Array,
                  h: Optional[int] = None, kh: Optional[int] = None,
-                 paged_kernel: Optional[bool] = None,
-                 layer: Optional[jax.Array] = None):
-    """Block-table attention over `t` new positions per row.
+                 paged_kernel: Optional[bool] = None, *,
+                 layer: jax.Array):
+    """Block-table attention over `t` new positions per row of layer
+    `layer` (an int32 scalar).
 
     x (b, t, d) holds each row's next `n_valid[b] <= t` tokens starting
     at logical position `cache.length[b]` (t == 1 is the decode step,
@@ -331,11 +336,10 @@ def paged_attend(cfg: ModelConfig, params: dict, x: jax.Array,
     beyond n_valid produce garbage outputs the caller must ignore (the
     pools stay clean outside the scratch block).
 
-    With `layer` (an int32 scalar) the cache holds the stacked
-    `(L, n_blocks, block_size, kh, hd)` pools: this layer's K/V are read
-    and written at `pool[layer]`, the other layers come back untouched,
-    and nothing copies a layer out of the stack, so a layer scan that
-    carries the pools updates them in place.
+    This layer's K/V are read and written at `pool[layer]` of the
+    stacked pools, the other layers come back untouched, and nothing
+    copies a layer out of the stack, so a layer scan that carries the
+    pools updates them in place.
 
     Two dispatch paths, selected by `paged_kernel` (falling back to
     `cfg.paged_kernel`):
@@ -351,13 +355,6 @@ def paged_attend(cfg: ModelConfig, params: dict, x: jax.Array,
     """
     from . import rope as rope_mod
 
-    if layer is None:
-        stacked = cache._replace(k_pool=cache.k_pool[None],
-                                 v_pool=cache.v_pool[None])
-        y, k_pool, v_pool = paged_attend(
-            cfg, params, x, stacked, angles, n_valid, h, kh, paged_kernel,
-            layer=jnp.int32(0))
-        return y, k_pool[0], v_pool[0]
     h = h or cfg.effective_n_heads
     kh = kh or cfg.n_kv_heads
     hd = params["wq"].shape[1] // h
@@ -378,21 +375,24 @@ def paged_attend(cfg: ModelConfig, params: dict, x: jax.Array,
         return y, k_pool, v_pool
     k_pool = _paged_write(cache.k_pool, k_new, cache, n_valid, layer)
     v_pool = _paged_write(cache.v_pool, v_new, cache, n_valid, layer)
-    block_size = k_pool.shape[2]
+    block_size = k_pool.shape[3]
     mb = cache.block_table.shape[1]
     S = mb * block_size
-    k = k_pool[layer, cache.block_table].reshape(b, S, kh, hd)
-    v = v_pool[layer, cache.block_table].reshape(b, S, kh, hd)
+    # each row's window, block by block: (b, mb, kh, bs, hd)
+    k = k_pool[layer, cache.block_table]
+    v = v_pool[layer, cache.block_table]
     g = h // kh
     qg = q.reshape(b, t, kh, g, hd) * hd**-0.5
-    s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
-                   preferred_element_type=jnp.float32)
+    s = jnp.einsum("bqkgd,bmksd->bkgqms", qg, k,
+                   preferred_element_type=jnp.float32).reshape(
+                       b, kh, g, t, S)
     q_pos = cache.length[:, None] + jnp.arange(t)[None, :]        # (b, t)
     kv_pos = jnp.arange(S)[None, None, :]                         # (1, 1, S)
     visible = kv_pos <= q_pos[:, :, None]                         # (b, t, S)
     s = jnp.where(visible[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", p, v).reshape(b, t, h * hd)
+    p = p.reshape(b, kh, g, t, mb, block_size)
+    out = jnp.einsum("bkgqms,bmksd->bqkgd", p, v).reshape(b, t, h * hd)
     y = out.astype(cdt) @ params["wo"].astype(cdt)
     return y, k_pool, v_pool
 

@@ -35,8 +35,8 @@ class PagedDecodeCaches(NamedTuple):
     inputs to `paged_step`, owned by the host-side
     `serving.paged_cache.PagedCacheManager`."""
 
-    k_pool: jax.Array   # (L, n_blocks, block_size, kh, hd)
-    v_pool: jax.Array   # (L, n_blocks, block_size, kh, hd)
+    k_pool: jax.Array   # (L, n_blocks, kh, block_size, hd), head-major
+    v_pool: jax.Array   # (L, n_blocks, kh, block_size, hd)
 
 
 def _remat(cfg: ModelConfig, fn):
@@ -273,11 +273,13 @@ class DecoderLM:
     def init_paged_caches(self, n_blocks: int,
                           block_size: int) -> PagedDecodeCaches:
         """Shared K/V block pools (no per-sequence state; see
-        `serving.paged_cache` for the allocator that owns block tables)."""
+        `serving.paged_cache` for the allocator that owns block tables).
+        Head-major, so a (block, head) tile is (block_size, hd) whatever
+        the KV-head count (`kernels.paged_attend`)."""
         cfg = self.cfg
         kh, hd = cfg.n_kv_heads, cfg.resolved_head_dim
         cdt = layers.dt(cfg.compute_dtype)
-        shape = (cfg.n_layers, n_blocks, block_size, kh, hd)
+        shape = (cfg.n_layers, n_blocks, kh, block_size, hd)
         return PagedDecodeCaches(k_pool=jnp.zeros(shape, cdt),
                                  v_pool=jnp.zeros(shape, cdt))
 

@@ -579,7 +579,7 @@ class ContinuousBatchingEngine:
         """Per-leaf physical-block axis of the paged-pool pytree, found by
         shape diffing init_paged_caches at two pool sizes — model-agnostic
         the same way `_detect_batch_axes` is, so the jitted copy-on-write
-        block copy works for dense `(L, n_blocks, bs, kh, hd)` pools and
+        block copy works for dense `(L, n_blocks, kh, bs, hd)` pools and
         the flat test pools alike."""
         big = jax.eval_shape(
             lambda: self.model.init_paged_caches(3, block_size))

@@ -71,7 +71,7 @@ number of tokens actually resident instead of `n_slots * cache_len`.
   indices the paged attention read path in `models/attention.py`
   consumes inside the jitted decode step.
 
-The device-side half — the `(L, n_blocks, block_size, kh, hd)` K/V pools
+The device-side half — the `(L, n_blocks, kh, block_size, hd)` K/V pools
 and the gather/scatter read/write path — lives with the models
 (`models/transformer.py` `init_paged_caches`/`paged_step`); the engine
 (`continuous_batching.py`) glues the two together, adds chunked prefill

@@ -5,8 +5,10 @@ the kernel (`kernels.paged_attend.paged_attend_fused`) must reproduce its
 outputs and pool writes within tight fp32 tolerance across decode (t=1)
 and chunked-prefill (t>1) shapes, including the block-boundary edge
 cases (lengths at block edges, inactive lanes, CoW-shared partial
-blocks, all-NULL table tails). Pool comparisons exclude physical block 0
-(NULL_BLOCK): it is scratch with unspecified content on both paths.
+blocks, all-NULL table tails). Pools are one layer's head-major stack,
+`(1, n_blocks, kh, bs, hd)`, attended as layer 0. Pool comparisons
+exclude physical block 0 (NULL_BLOCK): it is scratch with unspecified
+content on both paths.
 """
 import dataclasses
 
@@ -23,6 +25,7 @@ from repro.models import build_model, rope
 from repro.serving.continuous_batching import ContinuousBatchingEngine
 
 TOL = dict(rtol=2e-5, atol=1e-5)
+LAYER0 = jnp.int32(0)
 
 
 def _mini_cfg(**kw):
@@ -35,15 +38,16 @@ def _mini_cfg(**kw):
 
 def _mk_case(rng, cfg, b, t, bs, mb, lengths, n_valid, tables=None,
              null_garbage=False, dtype=np.float32):
-    """A PagedKVCache + inputs; row r owns blocks 1 + r*mb .. unless an
-    explicit `tables` layout (for shared/CoW cases) is given."""
+    """A PagedKVCache over one layer + inputs; row r owns blocks
+    1 + r*mb .. unless an explicit `tables` layout (for shared/CoW
+    cases) is given."""
     kh, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     n_blocks = b * mb + 2
-    kp = rng.normal(size=(n_blocks, bs, kh, hd)).astype(dtype)
-    vp = rng.normal(size=(n_blocks, bs, kh, hd)).astype(dtype)
+    kp = rng.normal(size=(1, n_blocks, kh, bs, hd)).astype(dtype)
+    vp = rng.normal(size=(1, n_blocks, kh, bs, hd)).astype(dtype)
     if null_garbage:  # prove NULL_BLOCK content never leaks into outputs
-        kp[0] = 1e6
-        vp[0] = -1e6
+        kp[:, 0] = 1e6
+        vp[:, 0] = -1e6
     if tables is None:
         tables = np.zeros((b, mb), np.int32)
         for r in range(b):
@@ -62,9 +66,9 @@ def _mk_case(rng, cfg, b, t, bs, mb, lengths, n_valid, tables=None,
 
 def _both_paths(cfg, params, x, cache, angles, nv):
     yg, kg, vg = A.paged_attend(cfg, params, x, cache, angles, nv,
-                                paged_kernel=False)
+                                paged_kernel=False, layer=LAYER0)
     yk, kk, vk = A.paged_attend(cfg, params, x, cache, angles, nv,
-                                paged_kernel=True)
+                                paged_kernel=True, layer=LAYER0)
     return (yg, kg, vg), (yk, kk, vk)
 
 
@@ -76,8 +80,8 @@ def _assert_parity(gather, kernel, nv, **tol):
                                **tol)
     # every pool block except the NULL scratch must match exactly: the
     # kernel's fused scatter writes the same cells the reference does
-    np.testing.assert_array_equal(np.asarray(kk)[1:], np.asarray(kg)[1:])
-    np.testing.assert_array_equal(np.asarray(vk)[1:], np.asarray(vg)[1:])
+    np.testing.assert_array_equal(np.asarray(kk)[:, 1:], np.asarray(kg)[:, 1:])
+    np.testing.assert_array_equal(np.asarray(vk)[:, 1:], np.asarray(vg)[:, 1:])
 
 
 @pytest.fixture(scope="module")
@@ -94,19 +98,15 @@ def test_parity_decode_t1(rng, mini, chunk_blocks):
     x, cache, angles, nv = _mk_case(rng, cfg, b=4, t=1, bs=4, mb=6,
                                     lengths=[5, 8, 0, 23], n_valid=[1, 1, 0, 1])
     yg, kg, vg = A.paged_attend(cfg, params, x, cache, angles, nv,
-                                paged_kernel=False)
+                                paged_kernel=False, layer=LAYER0)
     q, kn, vn = A._project_qkv(cfg, params, x, 4, 2, 8)
     q = rope.apply_rotary(q, angles)
     kn = rope.apply_rotary(kn, angles)
     out, kk, vk = paged_attend_fused(
         q, kn, vn, cache.k_pool, cache.v_pool, cache.block_table,
-        cache.length, nv, chunk_blocks=chunk_blocks)
+        cache.length, nv, chunk_blocks=chunk_blocks, layer=LAYER0)
     yk = out.reshape(4, 1, -1) @ params["wo"]
-    rows = np.asarray(nv) > 0
-    np.testing.assert_allclose(np.asarray(yk)[rows], np.asarray(yg)[rows],
-                               **TOL)
-    np.testing.assert_array_equal(np.asarray(kk)[1:], np.asarray(kg)[1:])
-    np.testing.assert_array_equal(np.asarray(vk)[1:], np.asarray(vg)[1:])
+    _assert_parity((yg, kg, vg), (yk, kk, vk), nv)
 
 
 @pytest.mark.parametrize("t,lengths,n_valid", [
@@ -132,8 +132,22 @@ def test_parity_bf16_pools(rng):
     np.testing.assert_allclose(np.asarray(yk, np.float32),
                                np.asarray(yg, np.float32), rtol=3e-2,
                                atol=3e-2)
-    np.testing.assert_array_equal(np.asarray(kk, np.float32)[1:],
-                                  np.asarray(kg, np.float32)[1:])
+    np.testing.assert_array_equal(np.asarray(kk, np.float32)[:, 1:],
+                                  np.asarray(kg, np.float32)[:, 1:])
+
+
+@pytest.mark.parametrize("t,lengths,n_valid", [
+    (1, [5, 16, 0, 37], [1, 1, 0, 1]),    # decode
+    (8, [13, 0], [8, 5]),                 # chunked prefill across a block
+], ids=["decode", "prefill"])
+def test_parity_kv_heads_not_a_multiple_of_8(rng, t, lengths, n_valid):
+    """phi3-medium's 4:1 group at 5 KV heads (its 40 / 10 halved twice):
+    a KV-head count no sublane tile divides."""
+    cfg = _mini_cfg(n_heads=20, n_kv_heads=5, head_dim=16)
+    params = A.init_attention(cfg, jax.random.key(2))
+    x, cache, angles, nv = _mk_case(rng, cfg, b=len(lengths), t=t, bs=16,
+                                    mb=4, lengths=lengths, n_valid=n_valid)
+    _assert_parity(*_both_paths(cfg, params, x, cache, angles, nv), nv)
 
 
 # --------------------------------------- block-boundary edge-case suite
@@ -186,13 +200,13 @@ def test_edge_prefill_crosses_cow_shared_partial_block(rng, mini):
     # seed the CoW copy with the shared block's content, as prepare_write
     # would have
     cache = cache._replace(
-        k_pool=cache.k_pool.at[5].set(cache.k_pool[2]),
-        v_pool=cache.v_pool.at[5].set(cache.v_pool[2]))
-    shared_k = np.asarray(cache.k_pool)[[1, 2]]
+        k_pool=cache.k_pool.at[:, 5].set(cache.k_pool[:, 2]),
+        v_pool=cache.v_pool.at[:, 5].set(cache.v_pool[:, 2]))
+    shared_k = np.asarray(cache.k_pool)[:, [1, 2]]
     g, k = _both_paths(cfg, params, x, cache, angles, nv)
     _assert_parity(g, k, nv)
     for _, kp, _vp in (g, k):
-        np.testing.assert_array_equal(np.asarray(kp)[[1, 2]], shared_k)
+        np.testing.assert_array_equal(np.asarray(kp)[:, [1, 2]], shared_k)
 
 
 def test_edge_null_tail_garbage_masked(rng, mini):
@@ -208,6 +222,31 @@ def test_edge_null_tail_garbage_masked(rng, mini):
     assert np.all(np.abs(np.asarray(k[0])) < 1e4)
 
 
+# ---------------------------------------------- chunks past every query
+@pytest.mark.parametrize("chunk_blocks", [1, 2])
+def test_parity_chunks_past_length(rng, mini, chunk_blocks):
+    """A chunked-prefill piece over a long table cut into small chunks,
+    most of them past every query position: the programs that skip them
+    leave outputs (padded lanes included) and pools as the gather path
+    has them. (`test_parity_decode_t1` covers decode.)"""
+    cfg, params = mini
+    t, lengths, n_valid = 8, [2, 21], [8, 5]
+    b = len(lengths)
+    x, cache, angles, nv = _mk_case(rng, cfg, b=b, t=t, bs=4, mb=12,
+                                    lengths=lengths, n_valid=n_valid,
+                                    null_garbage=True)
+    gather = A.paged_attend(cfg, params, x, cache, angles, nv,
+                            paged_kernel=False, layer=LAYER0)
+    q, kn, vn = A._project_qkv(cfg, params, x, 4, 2, 8)
+    q = rope.apply_rotary(q, angles)
+    kn = rope.apply_rotary(kn, angles)
+    out, kk, vk = paged_attend_fused(
+        q, kn, vn, cache.k_pool, cache.v_pool, cache.block_table,
+        cache.length, nv, chunk_blocks=chunk_blocks, layer=LAYER0)
+    _assert_parity(gather, (out.reshape(b, t, -1) @ params["wo"], kk, vk),
+                   nv)
+
+
 # ------------------------------------------------ stacked-pool (L, ...) form
 @pytest.mark.parametrize("layer", [0, 2])
 @pytest.mark.parametrize("t,lengths,n_valid,mb", [
@@ -216,18 +255,18 @@ def test_edge_null_tail_garbage_masked(rng, mini):
 ], ids=["decode", "prefill"])
 def test_stacked_pools_match_one_layer_form(rng, mini, layer, t, lengths,
                                             n_valid, mb):
-    """The kernel and the gather path given the layer stack and a layer
-    index attend and write exactly what they do given that layer alone,
-    and leave every other layer bit-identical."""
+    """The kernel and the gather path given a three-layer stack and a
+    layer index attend and write exactly what they do given that layer
+    as a stack of one, and leave every other layer bit-identical."""
     cfg, params = mini
     b, n_layers = len(lengths), 3
     x, cache, angles, nv = _mk_case(rng, cfg, b=b, t=t, bs=4, mb=mb,
                                     lengths=lengths, n_valid=n_valid)
-    shape = (n_layers,) + cache.k_pool.shape
+    shape = (n_layers,) + cache.k_pool.shape[1:]
     k_stack = jnp.asarray(rng.normal(size=shape).astype(np.float32))
     v_stack = jnp.asarray(rng.normal(size=shape).astype(np.float32))
-    k_stack = k_stack.at[layer].set(cache.k_pool)
-    v_stack = v_stack.at[layer].set(cache.v_pool)
+    k_stack = k_stack.at[layer].set(cache.k_pool[0])
+    v_stack = v_stack.at[layer].set(cache.v_pool[0])
     stacked = cache._replace(k_pool=k_stack, v_pool=v_stack)
     li = jnp.int32(layer)
     others = [i for i in range(n_layers) if i != layer]
@@ -237,26 +276,27 @@ def test_stacked_pools_match_one_layer_form(rng, mini, layer, t, lengths,
     q = rope.apply_rotary(q, angles)
     kn = rope.apply_rotary(kn, angles)
     args = (cache.block_table, cache.length, nv)
-    out4, k4, v4 = paged_attend_fused(q, kn, vn, cache.k_pool, cache.v_pool,
-                                      *args)
-    out5, k5, v5 = paged_attend_fused(q, kn, vn, k_stack, v_stack, *args,
+    out1, k1, v1 = paged_attend_fused(q, kn, vn, cache.k_pool, cache.v_pool,
+                                      *args, layer=LAYER0)
+    out3, k3, v3 = paged_attend_fused(q, kn, vn, k_stack, v_stack, *args,
                                       layer=li)
-    np.testing.assert_array_equal(np.asarray(out5), np.asarray(out4))
-    np.testing.assert_array_equal(np.asarray(k5[layer]), np.asarray(k4))
-    np.testing.assert_array_equal(np.asarray(v5[layer]), np.asarray(v4))
+    np.testing.assert_array_equal(np.asarray(out3), np.asarray(out1))
+    np.testing.assert_array_equal(np.asarray(k3[layer]), np.asarray(k1[0]))
+    np.testing.assert_array_equal(np.asarray(v3[layer]), np.asarray(v1[0]))
 
     gather = A.paged_attend(cfg, params, x, cache, angles, nv,
-                            paged_kernel=False)
-    kernel = (out5.reshape(b, t, -1) @ params["wo"], k5[layer], v5[layer])
+                            paged_kernel=False, layer=LAYER0)
+    kernel = (out3.reshape(b, t, -1) @ params["wo"],
+              k3[layer][None], v3[layer][None])
     _assert_parity(gather, kernel, nv)
-    yg5, kg5, vg5 = A.paged_attend(cfg, params, x, stacked, angles, nv,
+    yg3, kg3, vg3 = A.paged_attend(cfg, params, x, stacked, angles, nv,
                                    paged_kernel=False, layer=li)
-    np.testing.assert_array_equal(np.asarray(yg5), np.asarray(gather[0]))
-    np.testing.assert_array_equal(np.asarray(kg5[layer])[1:],
-                                  np.asarray(gather[1])[1:])
-    np.testing.assert_array_equal(np.asarray(vg5[layer])[1:],
-                                  np.asarray(gather[2])[1:])
-    for new_k, new_v in ((k5, v5), (kg5, vg5)):
+    np.testing.assert_array_equal(np.asarray(yg3), np.asarray(gather[0]))
+    np.testing.assert_array_equal(np.asarray(kg3[layer])[1:],
+                                  np.asarray(gather[1][0])[1:])
+    np.testing.assert_array_equal(np.asarray(vg3[layer])[1:],
+                                  np.asarray(gather[2][0])[1:])
+    for new_k, new_v in ((k3, v3), (kg3, vg3)):
         np.testing.assert_array_equal(np.asarray(new_k)[others],
                                       np.asarray(k_stack)[others])
         np.testing.assert_array_equal(np.asarray(new_v)[others],

@@ -4,7 +4,8 @@ The kernels run in interpret mode everywhere else in the suite; these
 tests hand them to Mosaic ahead of time, from shapes only, against a
 described `v5e:2x2` topology (no chip attached), so a kernel the chip's
 compiler would refuse fails here. Widths: phi4-mini-3.8b attention (24
-query / 8 KV heads, head_dim 128, 16-token blocks) for `paged_attend`,
+query / 8 KV heads, head_dim 128, 16-token blocks) and phi3-medium-14b's
+(40 / 10, a KV-head count no sublane tile divides) for `paged_attend`,
 the paper's retrieval point (8,192 documents at dim 512) for the scoring
 kernels. The topology is described inside a fixture, never at import:
 only one process may load the TPU library at a time.
@@ -14,7 +15,9 @@ with 4 layers and a 1,025-block pool, to show from `memory_analysis()`
 that it updates the KV pools in place: the donated pools alias the
 output and no temp buffer holds even one layer's pool. (XLA:CPU keeps a
 full-size temp for a scatter inside a loop even when donated, so only a
-compile for the TPU can show this.)
+compile for the TPU can show this.) phi3-medium's step, cut to 4
+layers the same way, is held to the same check on the fused kernel's
+path, the one its cell runs.
 """
 import dataclasses
 import os
@@ -54,15 +57,17 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _paged(t, b, mb):
+def _paged(t, b, mb, h=H, kh=KH):
+    """The kernel as the step calls it: a head-major two-layer stack of
+    512 blocks and a layer index."""
     def args(s):
         bf = jnp.bfloat16
-        return (s((b, t, H, HD), bf), s((b, t, KH, HD), bf),
-                s((b, t, KH, HD), bf), s((512, BS, KH, HD), bf),
-                s((512, BS, KH, HD), bf), s((b, mb), jnp.int32),
-                s((b,), jnp.int32), s((b,), jnp.int32))
-    return (lambda *a: paged_attend.paged_attend_fused(*a, interpret=False),
-            args)
+        return (s((b, t, h, HD), bf), s((b, t, kh, HD), bf),
+                s((b, t, kh, HD), bf), s((2, 512, kh, BS, HD), bf),
+                s((2, 512, kh, BS, HD), bf), s((b, mb), jnp.int32),
+                s((b,), jnp.int32), s((b,), jnp.int32), s((), jnp.int32))
+    return (lambda *a: paged_attend.paged_attend_fused(
+        *a[:-1], layer=a[-1], interpret=False), args)
 
 
 CASES = {
@@ -70,6 +75,10 @@ CASES = {
     "paged_attend_decode": _paged(t=1, b=16, mb=34),
     # one chunked-prefill piece at the engine's prefill_chunk
     "paged_attend_prefill": _paged(t=PREFILL_CHUNK, b=1, mb=32),
+    # phi3-medium-14b: 40 query / 10 KV heads
+    "paged_attend_decode_40_10": _paged(t=1, b=16, mb=34, h=40, kh=10),
+    "paged_attend_prefill_40_10": _paged(t=PREFILL_CHUNK, b=1, mb=32, h=40,
+                                         kh=10),
     "score_matmul_int": (
         lambda q, d: score_matmul.score_matmul_int(q, d, interpret=False),
         lambda s: (s((BATCH, DIM), jnp.int8), s((N_DOCS, DIM), jnp.int8))),
@@ -104,12 +113,9 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 STEP_LAYERS, POOL_BLOCKS, TABLE = 4, 1025, 128
 
 
-@pytest.mark.parametrize("paged_kernel", [True, False],
-                         ids=["kernel", "gather"])
-@pytest.mark.parametrize("b,t", [(16, 1), (1, PREFILL_CHUNK)],
-                         ids=["decode", "prefill"])
-def test_paged_step_updates_pools_in_place(one_chip, monkeypatch,
-                                           paged_kernel, b, t):
+def _compile_paged_step(one_chip, monkeypatch, arch, paged_kernel, b, t):
+    """The paged step of `arch`, cut to STEP_LAYERS layers, compiled for
+    the chip; asserts that the pools are updated in place."""
     from repro.configs import get_config
     from repro.models import build_model
     from repro.serving.continuous_batching import paged_step_program
@@ -117,7 +123,7 @@ def test_paged_step_updates_pools_in_place(one_chip, monkeypatch,
     # the kernel interprets when the default backend is not a TPU, as it
     # is here; compile what the chip runs
     monkeypatch.setattr(paged_attend, "resolve_interpret", lambda _: False)
-    cfg = dataclasses.replace(get_config("phi4-mini-3.8b"),
+    cfg = dataclasses.replace(get_config(arch),
                               n_layers=STEP_LAYERS, paged_kernel=paged_kernel)
     model = build_model(cfg)
 
@@ -141,3 +147,20 @@ def test_paged_step_updates_pools_in_place(one_chip, monkeypatch,
     assert mem.alias_size_in_bytes == pool_bytes
     assert mem.temp_size_in_bytes < one_layer_k
     assert ("tpu_custom_call" in compiled.as_text()) == paged_kernel
+
+
+@pytest.mark.parametrize("paged_kernel", [True, False],
+                         ids=["kernel", "gather"])
+@pytest.mark.parametrize("b,t", [(16, 1), (1, PREFILL_CHUNK)],
+                         ids=["decode", "prefill"])
+def test_paged_step_updates_pools_in_place(one_chip, monkeypatch,
+                                           paged_kernel, b, t):
+    _compile_paged_step(one_chip, monkeypatch, "phi4-mini-3.8b",
+                        paged_kernel, b, t)
+
+
+@pytest.mark.parametrize("b,t", [(16, 1), (1, PREFILL_CHUNK)],
+                         ids=["decode", "prefill"])
+def test_phi3_medium_paged_step_updates_pools_in_place(one_chip, monkeypatch,
+                                                       b, t):
+    _compile_paged_step(one_chip, monkeypatch, "phi3-medium-14b", True, b, t)
