@@ -1,9 +1,9 @@
 """ShardedDircIndex — multi-macro DIRC-RAG retrieval with incremental updates.
 
-The paper's system is sixteen DIRC cores inside ONE macro (handled by
-`topk.hierarchical_topk`); scaling past a single macro means replicating the
-whole macro and splitting the corpus across macros. This module models that
-outer level:
+The paper's system is sixteen DIRC cores inside ONE macro (`DircRagIndex`,
+whose comparator is `topk.hierarchical_topk`); scaling past a single macro
+means replicating the whole macro and splitting the corpus across macros.
+This module models that outer level:
 
   shard s  <->  one DIRC macro: its own per-document (per-"row") quantization
                 scales, two's-complement bit-plane image, D-Sum LUT and
@@ -14,12 +14,13 @@ outer level:
                 unchanged: the query is broadcast (query-stationary), the
                 documents never move.
 
-Top-k is a three-level comparator tree: per-core local top-k and per-macro
-merge via the existing `hierarchical_topk` (paper Fig. 3a), then a cross-
-macro global comparator that sorts the tiny candidate list by
-(-score, doc_id) — exactly `jax.lax.top_k`'s lower-index tie-break, so a
-sharded search equals a monolithic `DircRagIndex.search` up to fp reduction
-order (bit-exact on the integer paths).
+Top-k is two comparator levels: each macro's top-k by `topk.select_topk`,
+the Pallas selection kernel (`kernels/topk_select.py`) run over every
+(macro, query) row in one launch whatever the capacity, then a cross-macro
+global comparator that sorts the tiny candidate list by (-score, doc_id).
+Both keep `jax.lax.top_k`'s lower-index tie-break, so a sharded search
+equals a monolithic `DircRagIndex.search` up to fp reduction order
+(bit-exact on the integer paths).
 
 Incremental updates (the corpus is no longer build-once):
   * `add_docs` appends each new document to the least-loaded shard, writing
@@ -120,16 +121,20 @@ def _shard_map_scores(shard_fn, args, mesh) -> jax.Array:
     return mapped(*args)
 
 
-@partial(jax.jit, static_argnames=("k", "kk", "n_cores"))
-def _merge_impl(s, ids, alive, *, k: int, kk: int, n_cores: int) -> topk.TopK:
-    """Per-macro top-k (16-core comparator tree when the capacity folds)
-    then the cross-macro global comparator."""
-    capacity = s.shape[-1]
-    if capacity % n_cores == 0:
-        per_shard = jax.vmap(
-            lambda x: topk.hierarchical_topk(x, kk, n_cores=n_cores))(s)
-    else:
-        per_shard = jax.vmap(lambda x: topk.local_topk(x, kk))(s)
+@partial(jax.jit, static_argnames=("k", "kk", "mesh"))
+def _merge_impl(s, ids, alive, *, k: int, kk: int,
+                mesh=None) -> topk.TopK:
+    """Per-macro top-k by exact selection over every (macro, query) row,
+    then the cross-macro global comparator. Over a device `mesh` each
+    device selects among its own macros: XLA cannot partition a Mosaic
+    kernel by itself."""
+    select = partial(topk.select_topk, k=kk)
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+
+        select = jax.shard_map(select, mesh=mesh, in_specs=P(mesh.axis_names),
+                               out_specs=P(mesh.axis_names), check_vma=False)
+    per_shard = select(s)
     lv, li = per_shard.scores, per_shard.indices          # (S, b, kk)
     # slot -> stable global id; dead slots surface as -1
     masked_ids = jnp.where(alive, ids, -1)                # (S, cap)
@@ -437,7 +442,7 @@ class ShardedDircIndex:
     def search(
         self, queries: jax.Array, k: int, key: Optional[jax.Array] = None
     ) -> topk.TopK:
-        """Three-level comparator tree: cores -> macro -> global merge.
+        """Each macro's top-k by selection, then the global merge.
 
         Returns global doc ids; id -1 marks "fewer than k live documents".
         """
@@ -446,8 +451,7 @@ class ShardedDircIndex:
                 f"k={k} exceeds total slots {self.n_shards * self.capacity}")
         s = self.scores(queries, key=key)                    # (S, b, cap)
         return _merge_impl(s, self.ids, self.alive, k=k,
-                           kk=min(k, self.capacity),
-                           n_cores=self.config.n_cores)
+                           kk=min(k, self.capacity), mesh=self.mesh)
 
     # --------------------------------------------------------------- update
     def _grow(self, extra: int) -> None:

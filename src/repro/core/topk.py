@@ -6,6 +6,13 @@ buffer and a global comparator merges them. The same structure scales to a
 TPU pod: per-device local top-k + all-gather of candidates + global merge
 (see the flat-index searcher in `core/sharded_index.py`).
 
+Which comparator runs where: `DircRagIndex` (one macro) runs
+`hierarchical_topk`, or `local_topk` on a ragged database; every macro of
+a `ShardedDircIndex` runs `select_topk`, the Pallas selection kernel of
+`kernels/topk_select.py`, which finds each row's top-k in k passes with no
+sort, whatever the macro's capacity. All of them return `lax.top_k`'s ids
+and scores bit for bit.
+
 `jax.lax.top_k` breaks ties toward the LOWER index; the hierarchical merge
 preserves that order because core-local indices are offset monotonically.
 """
@@ -28,6 +35,15 @@ def local_topk(scores: jax.Array, k: int) -> TopK:
     """Plain top-k over the last axis."""
     v, i = jax.lax.top_k(scores, k)
     return TopK(scores=v, indices=i.astype(jnp.int32))
+
+
+def select_topk(scores: jax.Array, k: int) -> TopK:
+    """Top-k over the last axis by exact selection: every row of every
+    leading axis in one launch of the kernel (interpreted off the TPU)."""
+    from repro.kernels import ops as kops
+
+    v, i = kops.local_topk_blocks(scores, k)
+    return TopK(scores=v, indices=i)
 
 
 @partial(jax.jit, static_argnames=("k", "n_cores"))

@@ -2,7 +2,8 @@
 
   dirc_mac      bit-serial bit-plane MAC (paper-faithful digital CIM math)
   score_matmul  MXU-path INT8 score matmul (+fused cosine) — beyond-paper
-  topk_select   per-block local top-k (the local comparator)
+  topk_select   exact per-row top-k by selection, no sort (each macro's
+                comparator in ShardedDircIndex)
   paged_attend  fused paged-attention decode: flash-decoding split-KV over
                 the block table, new-token scatter folded into the launch
 

@@ -78,22 +78,43 @@ def score_matmul_cosine(q: jax.Array, docs: jax.Array, doc_norms: jax.Array,
     return out[0] if squeeze else out
 
 
-@partial(jax.jit, static_argnames=("k", "block_n"))
-def local_topk_blocks(scores: jax.Array, k: int,
-                      block_n: int = _topk.BLOCK_N):
-    """scores (b, n) -> global top-k via per-block kernel + tiny merge.
+def _topk_tiles(rows: int, n: int) -> tuple[int, int, int]:
+    """(blocks per row, block width, rows per tile) for a (rows, n) top-k.
 
-    Returns (vals (b, k), global idx (b, k)).
+    A row of up to `BLOCK_N` scores is one block, so no candidate merge
+    follows; a wider row splits into the fewest blocks of at most
+    `BLOCK_N`, 128-lane aligned. Tiles hold up to ROW_TILE * BLOCK_N
+    scores (1 MiB), rows in multiples of 8."""
+    nb = -(-n // _topk.BLOCK_N)
+    block_n = -(-n // nb)
+    block_n += (-block_n) % 128
+    per_tile = max(8, _topk.ROW_TILE * _topk.BLOCK_N // block_n // 8 * 8)
+    row_tile = min(per_tile, rows + (-rows) % 8)
+    return -(-n // block_n), block_n, row_tile
+
+
+@partial(jax.jit, static_argnames=("k",))
+def local_topk_blocks(scores: jax.Array, k: int):
+    """scores (..., n) -> exact top-k of each row: (vals (..., k), idx
+    (..., k) int32), `jax.lax.top_k`'s results bit for bit.
+
+    Every row of every leading axis goes through one kernel launch, which
+    reads the scores where they lie: nothing is padded or copied.
     """
-    b, n = scores.shape
-    s = _pad_axis(scores, 1, block_n, value=_topk.NEG_INF)
-    nb = s.shape[1] // block_n
-    vals, idx = _topk.blockwise_topk(s, k=k, block_n=block_n)  # (nb, b, k)
-    offs = (jnp.arange(nb, dtype=jnp.int32) * block_n)[:, None, None]
-    gidx = jnp.transpose(idx + offs, (1, 0, 2)).reshape(b, nb * k)
-    gvals = jnp.transpose(vals, (1, 0, 2)).reshape(b, nb * k)
-    # Candidates are block-major, score-desc within block, low-index
-    # tie-broken — top_k over them preserves the global low-index tie-break.
-    fv, fpos = jax.lax.top_k(gvals, k)
-    fidx = jnp.take_along_axis(gidx, fpos, axis=1)
-    return fv, fidx
+    *lead, n = scores.shape
+    assert k <= n, f"k={k} exceeds the row's {n} scores"
+    s = scores.reshape(-1, n)
+    nb, block_n, row_tile = _topk_tiles(s.shape[0], n)
+    vals, idx = _topk.blockwise_topk(s, k=k, block_n=block_n,
+                                     row_tile=row_tile)  # (nb, rows, k)
+    if nb == 1:
+        fv, fidx = vals[0], idx[0]
+    else:
+        offs = (jnp.arange(nb, dtype=jnp.int32) * block_n)[:, None, None]
+        gidx = jnp.transpose(idx + offs, (1, 0, 2)).reshape(-1, nb * k)
+        gvals = jnp.transpose(vals, (1, 0, 2)).reshape(-1, nb * k)
+        # Candidates are block-major, in selection order within a block:
+        # top_k's position tie-break over them is the low-index one.
+        fv, fpos = jax.lax.top_k(gvals, k)
+        fidx = jnp.take_along_axis(gidx, fpos, axis=1)
+    return fv.reshape(*lead, k), fidx.reshape(*lead, k)

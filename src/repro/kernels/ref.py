@@ -2,7 +2,8 @@
 
 Each function mirrors one kernel's public semantics with straightforward
 jnp — no blocking, no Pallas. Tests sweep shapes/dtypes and assert
-exact (integer) or allclose (float) agreement in interpret mode.
+exact (integer) or allclose (float) agreement in interpret mode. The
+top-k selection's oracle is `jax.lax.top_k` itself, matched bit for bit.
 """
 from __future__ import annotations
 
@@ -35,13 +36,3 @@ def score_matmul_cosine(
 ) -> jax.Array:
     ip = score_matmul_int(q, docs).astype(jnp.float32)
     return ip / jnp.maximum(q_norms * doc_norms, 1e-12)
-
-
-def blockwise_topk(scores: jax.Array, k: int, block_n: int):
-    """Oracle for kernels.topk_select: per-block top-k, low-index
-    tie-break, block axis first ((nb, b, k) like the kernel)."""
-    b, n = scores.shape
-    nb = n // block_n
-    s = jnp.transpose(scores.reshape(b, nb, block_n), (1, 0, 2))
-    vals, idx = jax.lax.top_k(s, k)
-    return vals, idx.astype(jnp.int32)

@@ -53,13 +53,74 @@ def test_score_matmul_cosine(rng):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
 
 
-@pytest.mark.parametrize("b,n,k", [(1, 512, 1), (3, 1200, 7), (2, 2048, 64)])
-def test_topk_kernel_sweep(rng, b, n, k):
-    s = jnp.asarray(rng.normal(size=(b, n)).astype(np.float32))
+def _assert_as_lax_top_k(s, k):
+    """The selection's ids and score bits equal `jax.lax.top_k`'s."""
     fv, fi = ops.local_topk_blocks(s, k=k)
     rv, ri = jax.lax.top_k(s, k)
-    assert (fi == ri).all()
-    np.testing.assert_allclose(np.asarray(fv), np.asarray(rv))
+    assert np.array_equal(np.asarray(fi), np.asarray(ri))
+    assert np.array_equal(np.asarray(fv).view(np.int32),
+                          np.asarray(rv).view(np.int32))
+
+
+# (2048, 8171, 10) is the Quora cell's flush: 64 macros x 32 queries over
+# 8,171 slots, a multiple of neither 16 nor 128; 20,000 splits into blocks
+@pytest.mark.parametrize("b,n,k", [(1, 512, 1), (3, 1200, 7), (2, 2048, 64),
+                                   (2048, 8171, 10), (5, 515, 10),
+                                   (4, 20000, 10), (3, 10, 10)])
+def test_topk_kernel_sweep(rng, b, n, k):
+    s = jnp.asarray(rng.normal(size=(b, n)).astype(np.float32))
+    _assert_as_lax_top_k(s, k)
+
+
+def _rows_two_live(rng):
+    """Regression: a -3e38 mask outranked the -inf of dead slots, so a
+    row with fewer than k live scores picked a slot again ([3, 700, 3,
+    3]); lax.top_k gives [3, 700, 0, 1]."""
+    s = np.full((2, 8171), -np.inf, np.float32)
+    s[0, [3, 700]] = [1.0, 0.5]
+    return s
+
+
+def _rows_dead(rng):
+    return np.full((3, 8171), -np.inf, np.float32)
+
+
+def _rows_tombstoned(rng):
+    s = rng.normal(size=(6, 2048)).astype(np.float32)
+    s[rng.random(size=s.shape) < 0.3] = -np.inf
+    s[1, 11:] = -np.inf                  # 11 live slots and tombstones
+    return s
+
+
+def _rows_tied(rng):
+    """Duplicated documents: equal scores go to the lower slot."""
+    return rng.integers(-2, 3, size=(4, 8171)).astype(np.float32)
+
+
+def _rows_signed_zeros(rng):
+    """lax.top_k ranks +0.0 above -0.0 (total order), and so must the
+    selection."""
+    s = np.zeros((2, 1000), np.float32)
+    s[:, rng.random(size=1000) < 0.5] = -0.0
+    s[1, :5] = -np.inf
+    return s
+
+
+def _rows_past_last_block(rng):
+    """Blocks of a row wider than one block; the last one runs past the
+    row's end with the row's only live scores in it."""
+    s = np.full((3, 16390), -np.inf, np.float32)
+    s[0] = rng.normal(size=16390)
+    s[1, [16389, 16000]] = [2.0, 3.0]
+    return s
+
+
+@pytest.mark.parametrize("make", [_rows_two_live, _rows_dead,
+                                  _rows_tombstoned, _rows_tied,
+                                  _rows_signed_zeros, _rows_past_last_block],
+                         ids=lambda f: f.__name__[len("_rows_"):])
+def test_topk_kernel_rows_as_lax_top_k(rng, make):
+    _assert_as_lax_top_k(jnp.asarray(make(rng)), 10)
 
 
 def test_topk_kernel_ties():

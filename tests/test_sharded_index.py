@@ -1,14 +1,23 @@
 """ShardedDircIndex: sharded-vs-monolithic parity and incremental updates."""
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs.dirc_rag import RETRIEVAL_INT8
 from repro.core import error_model as E
-from repro.core import retrieval
+from repro.core import retrieval, topk
 from repro.core.retrieval import DircRagIndex, RetrievalConfig
 from repro.core.sharded_index import ShardedDircIndex
 from repro.data.synthetic import make_ir_dataset
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+from chip_bench.reference import search as ref_search  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -85,6 +94,67 @@ def test_ragged_corpus_shards(ds):
     mono = DircRagIndex.build(emb, cfg).search(q, k=5)
     sh = ShardedDircIndex.build(emb, cfg, n_shards=4).search(q, k=5)
     _assert_parity(mono, sh)
+
+
+def _corpus_ragged(rng):
+    """2,363 documents: 37 slots a macro, the last slots never written."""
+    return rng.normal(size=(64 * 37 - 5, 64)).astype(np.float32), []
+
+
+def _corpus_few_live(rng):
+    """Two live slots a macro, fewer than k, the rest tombstones."""
+    docs = rng.normal(size=(64 * 12, 64)).astype(np.float32)
+    return docs, [i for i in range(len(docs)) if i % 12 >= 2]
+
+
+def _corpus_nearly_empty(rng):
+    """Fewer live documents than k in the whole index: ids -1 follow."""
+    docs = rng.normal(size=(64 * 12, 64)).astype(np.float32)
+    return docs, [i for i in range(len(docs)) if i % 97]
+
+
+def _corpus_tombstoned(rng):
+    docs = rng.normal(size=(64 * 40 + 3, 64)).astype(np.float32)
+    return docs, np.flatnonzero(rng.random(len(docs)) < 0.3).tolist()
+
+
+def _corpus_duplicated(rng):
+    """40 distinct documents, each copied ~59 times: every top-k is a tie,
+    within macros and across them, broken by the lower id."""
+    base = rng.normal(size=(40, 64)).astype(np.float32)
+    return base[rng.integers(0, 40, size=64 * 37 - 5)], []
+
+
+@pytest.mark.parametrize("make", [_corpus_ragged, _corpus_few_live,
+                                  _corpus_nearly_empty, _corpus_tombstoned,
+                                  _corpus_duplicated],
+                         ids=lambda f: f.__name__[len("_corpus_"):])
+def test_macro_topk_is_exact(make):
+    """Over 64 macros, each macro's top-k is `jax.lax.top_k`'s bit for
+    bit, and the search returns exact int8 search's top-10 ids
+    (`chip_bench/reference/search.py`), -1 past the live documents."""
+    rng = np.random.default_rng(17)
+    docs, deleted = make(rng)
+    sh = ShardedDircIndex.build(jnp.asarray(docs), RETRIEVAL_INT8,
+                                n_shards=64)
+    sh.delete_docs(deleted)
+    q = docs[rng.integers(0, len(docs), size=8)]
+    q = q + 0.3 * rng.normal(size=q.shape).astype(np.float32)
+    k = 10
+    s = sh.scores(jnp.asarray(q))                       # (64, 8, cap)
+    kk = min(k, sh.capacity)
+    got = topk.select_topk(s, kk)
+    want_v, want_i = jax.lax.top_k(s, kk)
+    assert np.array_equal(np.asarray(got.indices), np.asarray(want_i))
+    assert np.array_equal(np.asarray(got.scores).view(np.int32),
+                          np.asarray(want_v).view(np.int32))
+
+    live = np.setdiff1d(np.arange(len(docs)), deleted)
+    ref = live[ref_search.ExactIndex(docs[live]).topk(q, k)]
+    ids = np.asarray(sh.search(jnp.asarray(q), k).indices)
+    n = min(k, len(live))
+    assert np.array_equal(ids[:, :n], ref[:, :n])
+    assert (ids[:, n:] == -1).all()
 
 
 def test_add_docs_balances_and_retrieves(ds):

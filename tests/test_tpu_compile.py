@@ -7,8 +7,10 @@ compiler would refuse fails here. Widths: phi4-mini-3.8b attention (24
 query / 8 KV heads, head_dim 128, 16-token blocks) and phi3-medium-14b's
 (40 / 10, a KV-head count no sublane tile divides) for `paged_attend`,
 the paper's retrieval point (8,192 documents at dim 512) for the scoring
-kernels. The topology is described inside a fixture, never at import:
-only one process may load the TPU library at a time.
+kernels, and the Quora cell's flush (64 macros of 8,171 slots, 32
+queries) for each macro's top-k selection. The topology is described
+inside a fixture, never at import: only one process may load the TPU
+library at a time.
 
 The engine's paged step program is compiled too, at phi4-mini widths
 with 4 layers and a 1,025-block pool, to show from `memory_analysis()`
@@ -27,6 +29,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import sharded_index
 from repro.kernels import dirc_mac, paged_attend, score_matmul, topk_select
 
 H, KH, HD, BS = 24, 8, 128, 16
@@ -94,19 +97,57 @@ CASES = {
     "blockwise_topk": (
         lambda x: topk_select.blockwise_topk(x, k=10, interpret=False),
         lambda s: (s((BATCH, N_DOCS), jnp.float32),)),
+    # the Quora cell's per-macro top-k: 64 macros of 8,171 slots, 32 queries
+    "index_topk_select_quora": (
+        lambda s, ids, alive: sharded_index._merge_impl(s, ids, alive,
+                                                        k=10, kk=10),
+        lambda s: (s((64, 32, 8171), jnp.float32), s((64, 8171), jnp.int32),
+                   s((64, 8171), jnp.bool_))),
 }
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
-def test_kernel_compiles_for_v5e(one_chip, name):
+def test_kernel_compiles_for_v5e(one_chip, monkeypatch, name):
     fn, make_args = CASES[name]
+    # programs that call a kernel interpret it when the default backend is
+    # not a TPU, as it is here; compile what the chip runs, and let no
+    # trace of it outlive the test
+    monkeypatch.setattr(topk_select, "resolve_interpret", lambda _: False)
 
     def shaped(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    compiled = jax.jit(fn).lower(*make_args(shaped)).compile()
+    jax.clear_caches()
+    try:
+        compiled = jax.jit(fn).lower(*make_args(shaped)).compile()
+    finally:
+        jax.clear_caches()
     assert "tpu_custom_call" in compiled.as_text(), \
         f"{name} did not lower to a Mosaic kernel"
+
+
+def test_index_topk_compiles_over_a_mesh(topo, monkeypatch):
+    """A `shard_map` index over four chips: XLA cannot partition a Mosaic
+    kernel, so each chip selects among its own macros."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    monkeypatch.setattr(topk_select, "resolve_interpret", lambda _: False)
+    mesh = Mesh(np.array(topo.devices), ("macro",))
+    rep = NamedSharding(mesh, P())
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    jax.clear_caches()
+    try:
+        compiled = sharded_index._merge_impl.lower(
+            shaped((4, 16, 2048), jnp.float32), shaped((4, 2048), jnp.int32),
+            shaped((4, 2048), jnp.bool_), k=5, kk=5, mesh=mesh).compile()
+    finally:
+        jax.clear_caches()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 # phi4-mini's step program with the cell's pool, cut to 4 layers
